@@ -20,7 +20,7 @@ import jax
 import numpy as np
 
 from repro.core import ElasticScheduler, FixedScheduler
-from repro.core.latency_model import CPU_HOST, AnalyticDeviceModel
+from repro.core.latency_model import AnalyticDeviceModel, device_spec
 from repro.models import ArchConfig, build_model
 from repro.serving import (DATASETS, ModelBackend, PoissonWorkload,
                            ServingEngine, chunk_distribution)
@@ -62,7 +62,7 @@ def run(mode, chunk=None):
     be = ModelBackend(model, params, n_slots=N_SLOTS, max_len=MAX_LEN,
                       decode_mode="ar" if mode == "ar" else "elastic")
     if mode == "elastic":
-        an = AnalyticDeviceModel(cfg, CPU_HOST)
+        an = AnalyticDeviceModel(cfg, device_spec())
         samples = [(b, c, an.step_latency(b, c, 64))
                    for b in [1, 2, 4, 8] for c in [1, 2, 4, 8]]
         sch = ElasticScheduler.from_profile(samples, candidates=(2, 4, 8),

@@ -15,7 +15,7 @@ intermediate.  Two uses:
 
 from __future__ import annotations
 
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 # Call-like primitives whose inner jaxpr should be walked transparently
 # (the wrapper eqn itself moves no bytes).

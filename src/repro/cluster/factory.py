@@ -14,9 +14,10 @@ from __future__ import annotations
 from repro.cluster.admission import KVAdmissionPolicy
 from repro.cluster.engine import ClusterEngine
 from repro.cluster.router import make_router
-from repro.core.latency_model import CPU_HOST, TPU_V5E, AnalyticDeviceModel
+from repro.core.latency_model import TPU_V5E, AnalyticDeviceModel, device_spec
 from repro.core.scheduler import scheduler_for_mode
 from repro.serving import EngineCore, ModelBackend, SimBackend
+from repro.serving.backends import hbm_pool_pages
 
 
 def make_replica_scheduler(backend, profile, mode: str = "elastic"):
@@ -109,6 +110,12 @@ def build_model_cluster(model, params, n_replicas: int, router, *, profile,
     signal it reads from SimBackend replicas."""
     if isinstance(router, str):
         router = make_router(router)
+    if kv_pages is None and model.supports_paged():
+        # the replicas share the devices: split the HBM left after the
+        # weights between their pools (None on the host CPU: the
+        # backend's default)
+        kv_pages = hbm_pool_pages(model, page_size=page_size,
+                                  kv_shards=kv_shards, replicas=n_replicas)
     replicas = []
     for i in range(n_replicas):
         be = ModelBackend(model, params, n_slots=n_slots, max_len=max_len,
@@ -120,7 +127,7 @@ def build_model_cluster(model, params, n_replicas: int, router, *, profile,
                           prefix_cache=prefix_cache,
                           host_kv_pages=host_kv_pages)
         sch = scheduler_for_mode(
-            mode, AnalyticDeviceModel(model.cfg, CPU_HOST),
+            mode, AnalyticDeviceModel(model.cfg, device_spec()),
             prior_tokens_per_step=profile.tokens_per_step_bd32,
             batches=(1, 2, 4, 8, 16), ctx=float(max_len)) \
             if mode == "elastic" else scheduler_for_mode(

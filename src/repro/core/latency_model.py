@@ -43,6 +43,29 @@ CPU_HOST = DeviceSpec("cpu-host", 1e11, 3e10, 1e10, 1e-4, 32 * 2**30,
 
 DEVICES = {d.name: d for d in (TPU_V5E, A100_80G, CPU_HOST)}
 
+# ``jax.Device.device_kind`` → spec, for the device the process serves on
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
+
+def device_spec(device=None) -> DeviceSpec:
+    """The :class:`DeviceSpec` of ``device`` (default ``jax.devices()[0]``).
+
+    The host CPU (tests, the CPU rehearsal) maps to :data:`CPU_HOST`; an
+    accelerator whose ``device_kind`` is not in :data:`DEVICE_KINDS` is an
+    error — a cost model of some other chip would silently mis-size the
+    prefill budget and the swap-vs-recompute crossover."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return CPU_HOST
+    try:
+        return DEVICE_KINDS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no DeviceSpec for {device.platform} device kind "
+            f"{device.device_kind!r}; add it to DEVICE_KINDS") from None
+
 
 # ---------------------------------------------------------------------------
 # Analytic workload model

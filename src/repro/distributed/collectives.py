@@ -28,18 +28,6 @@ from jax.sharding import PartitionSpec as P
 NEG_INF = -1e30
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: new API (``check_vma``) when
-    present, ``jax.experimental.shard_map`` (``check_rep``) otherwise —
-    replication checking off in both (bodies use explicit collectives)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # int8 stochastic-rounding compressed gradient reduction
 # ---------------------------------------------------------------------------
@@ -101,10 +89,10 @@ def compressed_grad_sync(grads, mesh, data_axes, rng, block: int = 256):
     rngs = jax.random.split(rng, len(leaves))
 
     def one(g, r):
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             functools.partial(compressed_psum, axis_name=axis, rng=r,
                               block=block),
-            mesh=mesh, in_specs=P(), out_specs=P())
+            mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False)
         return fn(g)
 
     return treedef.unflatten([one(g, r) for g, r in zip(leaves, rngs)])
@@ -134,11 +122,11 @@ def split_kv_attention(q, k_cache, v_cache, kv_lens, mesh, *,
     on S over ``seq_axis`` → exact attention output [B,c,H,D]."""
     scale = scale if scale is not None else 1.0 / np.sqrt(q.shape[-1])
     body = functools.partial(_split_kv_body, axis_name=seq_axis, scale=scale)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(None, seq_axis, None, None),
                   P(None, seq_axis, None, None), P()),
-        out_specs=P())
+        out_specs=P(), check_vma=False)
     return fn(q, k_cache, v_cache, kv_lens)
 
 
@@ -193,7 +181,7 @@ def _local_slots(tables, ctx_lens, stripe_offs, shard, *, n_shards,
 
 def split_kv_paged_partial(q, k_pages, v_pages, block_tables, ctx_lens,
                            stripe_offs, ks: KVShardSpec, *,
-                           impl: str = "kernel", interpret: bool = True,
+                           interpret: bool, impl: str = "kernel",
                            scale: float | None = None):
     """Split-KV chunked paged attention across ``ks.axis``.
 
@@ -226,10 +214,10 @@ def split_kv_paged_partial(q, k_pages, v_pages, block_tables, ctx_lens,
         from repro.kernels.ops import merge_flash_partials
         return merge_flash_partials([part], axis_name=ks.axis)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=ks.mesh,
         in_specs=(P(), P(ks.axis), P(ks.axis), P(), P(), P()),
-        out_specs=(P(), P(), P()))
+        out_specs=(P(), P(), P()), check_vma=False)
     return fn(q, k_pages, v_pages, block_tables.astype(jnp.int32),
               ctx_lens.astype(jnp.int32), stripe_offs.astype(jnp.int32))
 
@@ -257,8 +245,8 @@ def scatter_pages_sharded(pages, new, dest, ks: KVShardSpec):
             new_.astype(pg.dtype).reshape(L, -1, KVH, hd), mode="drop")
         return flat.reshape(L, P_loc, ps, KVH, hd)
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=ks.mesh,
         in_specs=(P(None, ks.axis), P(), P()),
-        out_specs=P(None, ks.axis))
+        out_specs=P(None, ks.axis), check_vma=False)
     return fn(pages, new, dest)

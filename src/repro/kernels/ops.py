@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites work in CPU
-tests and on real hardware.
+``interpret=None`` resolves through :func:`resolve_interpret` — the one
+place the repository decides between a compiled kernel and Pallas
+interpret mode — so the same call sites work in CPU tests and on the chip.
 """
 
 from __future__ import annotations
@@ -16,7 +17,13 @@ from repro.kernels.block_diffusion_attn import block_diffusion_attention_kernel
 from repro.kernels.chunked_paged_attn import paged_chunk_attention_kernel
 
 
-def _default_interpret() -> bool:
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """Pallas interpret mode for a kernel call: an explicit value wins;
+    ``None`` compiles the kernel on a TPU backend and interprets it on any
+    other (CPU tests).  Every kernel call site of the models, the split-KV
+    collectives and the wrappers below resolves through here."""
+    if interpret is not None:
+        return interpret
     return jax.default_backend() != "tpu"
 
 
@@ -98,7 +105,7 @@ def paged_chunk_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
     Returns (acc [B,c,H,D] fp32, m [B,c,H], l [B,c,H]); combine with the
     in-window part via ``combine_with_window``.
     """
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return paged_chunk_attention_kernel(
         q, k_pages, v_pages, block_tables.astype(jnp.int32),
         ctx_lens.astype(jnp.int32), scale=scale, interpret=interpret)
@@ -113,7 +120,7 @@ def paged_chunk_attention_full(q, k_pages, v_pages, block_tables, ctx_lens,
     per-iteration attention of Optimus chunked decoding."""
     from repro.models.layers import block_causal_mask, sdpa_partial
 
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     acc_p, m_p, l_p = paged_chunk_attention_kernel(
         q, k_pages, v_pages, block_tables.astype(jnp.int32),
         ctx_lens.astype(jnp.int32), scale=scale, interpret=interpret)
@@ -135,7 +142,7 @@ def block_diffusion_attention(q, k, v, lengths, *, block_size: int,
                               q_tile: int = 128, kv_tile: int = 128,
                               scale=None, interpret=None):
     """Block-causal flash attention (prefill / training forward)."""
-    interpret = _default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return block_diffusion_attention_kernel(
         q, k, v, lengths.astype(jnp.int32), block_size=block_size,
         q_tile=q_tile, kv_tile=kv_tile, scale=scale, interpret=interpret)

@@ -16,14 +16,12 @@ import traceback   # noqa: E402
 
 import jax         # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_ccache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
-
 from repro.configs import SHAPES, cells, get_config            # noqa: E402
 from repro.core.latency_model import (active_param_count,      # noqa: E402
                                       kv_bytes_per_token,
                                       total_param_count)
 from repro.distributed.sharding import use_rules               # noqa: E402
+from repro.launch.compile_cache import configure_compile_cache  # noqa: E402
 from repro.launch.mesh import make_production_mesh             # noqa: E402
 from repro.launch.specs import build_cell                      # noqa: E402
 
@@ -74,8 +72,6 @@ def cost_summary(compiled) -> dict:
         ca = compiled.cost_analysis()
     except Exception as e:                                     # noqa: BLE001
         return {"error": str(e)}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     return {k: float(v) for k, v in ca.items()
             if isinstance(v, (int, float)) and (
                 "flops" in k or "bytes" in k or "utilization" in k.lower())}
@@ -161,6 +157,8 @@ def main():
                     help="decode chunk size override")
     ap.add_argument("--tag", default="", help="suffix for output filename")
     args = ap.parse_args()
+    configure_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
     rule_overrides = json.loads(args.rules_override) \
         if args.rules_override else None
     cfg_overrides = json.loads(args.cfg_override) if args.cfg_override \

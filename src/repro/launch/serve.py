@@ -4,8 +4,11 @@
         --mode elastic --dataset sharegpt --rate 2.0 --requests 100
 
 ``--backend sim`` (default) runs the virtual-clock simulation calibrated to
-the chosen device; ``--backend model`` serves a real (smoke-config) model on
-CPU end-to-end through the same engine.
+the chosen device; ``--backend model`` serves the real model through the
+same engine on the local device: the arch's published widths with random
+seeded weights, ``--layers N`` cutting depth to what one chip's HBM holds
+(SDAR-8B on one v5e: ``--layers 16``), or ``--smoke`` for the arch's
+reduced CPU config.  The page pool takes the HBM left after the weights.
 """
 
 from __future__ import annotations
@@ -15,12 +18,14 @@ import argparse
 import jax
 
 from repro.configs import get_config, get_smoke_config
-from repro.core.latency_model import DEVICES
+from repro.core.latency_model import DEVICES, AnalyticDeviceModel, device_spec
 from repro.core.scheduler import ElasticScheduler, scheduler_for_mode
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models.registry import build_model
 from repro.serving import (DATASETS, ModelBackend, ServingEngine,
                            SimBackend, Tracer, chunk_distribution,
                            make_trace)
+from repro.serving.backends import hbm_pool_pages
 
 
 def make_scheduler(mode: str, backend, profile):
@@ -69,12 +74,98 @@ def run_single_replica_faults(args, profile):
                          or "none"))
 
 
+def init_model(cfg, seed: int, kv_shards: int = 1):
+    """(model, params) with random weights from ``seed``, created inside
+    one jit on the device — replicated over the ``kv`` mesh when the page
+    pool is sharded, so no device holds a second copy."""
+    model = build_model(cfg)
+    sharding = None
+    if kv_shards > 1:
+        from jax.sharding import NamedSharding, PartitionSpec
+        from repro.launch.mesh import make_kv_mesh
+        sharding = NamedSharding(make_kv_mesh(kv_shards), PartitionSpec())
+    params = jax.jit(model.init, out_shardings=sharding)(
+        jax.random.PRNGKey(seed))
+    return model, params
+
+
+def model_serving(model, params, requests, mode: str, profile,
+                  seed: int = 0, **backend_kw):
+    """(ModelBackend, scheduler) for ``requests`` on the local device.
+    Requests without token ids get ids drawn from ``seed``; the block
+    table spans the longest request."""
+    import numpy as np
+    cfg = model.cfg
+    rng = np.random.default_rng(seed)
+    for r in requests:
+        if r.prompt_tokens is None:
+            r.prompt_tokens = rng.integers(
+                4, cfg.vocab_size, r.prompt_len).tolist()
+    max_len = max(r.prompt_len + r.max_new_tokens for r in requests)
+    if backend_kw.get("kv_pages") is None and model.supports_paged():
+        # this process owns the devices: the page pool takes the HBM left
+        # after the weights (None on the host CPU: the backend's default)
+        backend_kw["kv_pages"] = hbm_pool_pages(
+            model, page_size=backend_kw.get("page_size"),
+            kv_shards=backend_kw.get("kv_shards", 1))
+    # attention families serve through the paged KV pool (prompt-pages-
+    # only admission + incremental growth)
+    backend = ModelBackend(model, params, max_len=max_len,
+                           decode_mode="ar" if mode == "ar" else "elastic",
+                           **backend_kw)
+    if mode == "elastic":
+        # wall-clock-free scheduler from the local device's analytic model
+        sched = ElasticScheduler.from_analytic(
+            AnalyticDeviceModel(cfg, device_spec()),
+            prior_tokens_per_step=profile.tokens_per_step_bd32,
+            batches=(1, 2, 4, 8), ctx=128.0)
+    else:
+        sched = make_scheduler(mode, None, profile)
+    return backend, sched
+
+
+def build_model_serving(args, profile, wl_kw):
+    """``--backend model``: (ModelBackend, trace, scheduler)."""
+    if args.smoke:
+        cfg = get_smoke_config(args.arch)
+        lengths = {"max_prompt": 64, "max_output": 64}
+    else:
+        cfg = get_config(args.arch)
+        if args.layers is not None:
+            print(f"depth cut: {args.layers} of {cfg.n_layers} layers "
+                  f"of {cfg.name} at published widths")
+            cfg = cfg.replace(n_layers=args.layers)
+        lengths = {}
+    model, params = init_model(cfg, args.seed, args.kv_shards)
+    mkw = dict(wl_kw, **lengths)
+    if args.workload == "shared":
+        # real ids stay inside the vocab (away from the reserved mask/eos
+        # ids at the top)
+        mkw.update(vocab=max(cfg.vocab_size - 8, 16), prefix_len=32)
+    wl = make_trace(profile, args.workload, args.rate, args.requests,
+                    seed=args.seed, **mkw)
+    backend, sched = model_serving(
+        model, params, wl.requests, args.mode, profile, seed=args.seed,
+        obs=args.obs,
+        kv_pages=args.kv_pages, prefill_mode=args.prefill_mode,
+        prefill_token_budget=args.prefill_budget, kv_shards=args.kv_shards,
+        prefix_cache=not args.no_prefix_cache,
+        host_kv_pages=args.host_kv_pages)
+    return backend, wl, sched
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="sdar-8b")
     ap.add_argument("--mode", default="elastic",
                     help="elastic | ar | bd<chunk> (e.g. bd32)")
     ap.add_argument("--backend", default="sim", choices=["sim", "model"])
+    ap.add_argument("--layers", type=int, default=None,
+                    help="model backend: serve this many of the arch's "
+                         "layers at its published widths (depth cut)")
+    ap.add_argument("--smoke", action="store_true",
+                    help="model backend: serve the arch's reduced SMOKE "
+                         "config with prompts/outputs ≤ 64 tokens (CPU)")
     ap.add_argument("--device", default="tpu-v5e", choices=list(DEVICES))
     ap.add_argument("--dataset", default="sharegpt", choices=list(DATASETS))
     ap.add_argument("--rate", type=float, default=2.0)
@@ -84,7 +175,7 @@ def main():
                     help="out-block streaming for large chunks")
     ap.add_argument("--kv-pages", type=int, default=None,
                     help="KV pool pages (sim default 65536; model default "
-                         "mirrors 8 slots × max_len)")
+                         "fills the HBM left after the weights)")
     ap.add_argument("--kv-shards", type=int, default=1,
                     help="split the paged KV pool across this many devices "
                          "on a 'kv' mesh axis (model backend: split-KV "
@@ -132,6 +223,7 @@ def main():
                          "schedule (e.g. 'stall@1:r0:dur=0.5:slow=4'); "
                          "see serve_cluster for multi-replica failover")
     args = ap.parse_args()
+    configure_compile_cache()
 
     profile = DATASETS[args.dataset]
     if args.faults:
@@ -158,47 +250,7 @@ def main():
                         seed=args.seed, **wl_kw)
         sched = make_scheduler(args.mode, backend, profile)
     else:
-        cfg = get_smoke_config(args.arch)
-        model = build_model(cfg)
-        params = model.init(jax.random.PRNGKey(0))
-        # attention families serve through the paged KV pool automatically
-        # (prompt-pages-only admission + incremental growth)
-        backend = ModelBackend(model, params, n_slots=8, max_len=256,
-                               decode_mode="ar" if args.mode == "ar"
-                               else "elastic", obs=args.obs,
-                               kv_pages=args.kv_pages,
-                               prefill_mode=args.prefill_mode,
-                               prefill_token_budget=args.prefill_budget,
-                               kv_shards=args.kv_shards,
-                               prefix_cache=not args.no_prefix_cache,
-                               host_kv_pages=args.host_kv_pages)
-        import numpy as np
-        rng = np.random.default_rng(args.seed)
-        mkw = dict(wl_kw)
-        if args.workload == "shared":
-            # real ids must stay inside the smoke vocab (away from the
-            # reserved mask/eos ids at the top)
-            mkw.update(vocab=max(cfg.vocab_size - 8, 16), prefix_len=32)
-        wl = make_trace(profile, args.workload, args.rate, args.requests,
-                        seed=args.seed, max_prompt=64, max_output=64, **mkw)
-        for r in wl.requests:
-            r.prompt_len = min(r.prompt_len, 64)
-            r.max_new_tokens = min(r.max_new_tokens, 64)
-            if r.prompt_tokens is not None:
-                # shared trace carries real ids; just clamp to max_prompt
-                r.prompt_tokens = r.prompt_tokens[:r.prompt_len]
-            else:
-                r.prompt_tokens = rng.integers(
-                    4, cfg.vocab_size, r.prompt_len).tolist()
-        # wall-clock-free scheduler from a quick analytic stand-in
-        from repro.core.latency_model import AnalyticDeviceModel, CPU_HOST
-        if args.mode == "elastic":
-            sched = ElasticScheduler.from_analytic(
-                AnalyticDeviceModel(cfg, CPU_HOST),
-                prior_tokens_per_step=profile.tokens_per_step_bd32,
-                batches=(1, 2, 4, 8), ctx=128.0)
-        else:
-            sched = make_scheduler(args.mode, None, profile)
+        backend, wl, sched = build_model_serving(args, profile, wl_kw)
 
     tracer = Tracer() if args.trace else None
     engine = ServingEngine(backend, sched, max_batch=args.max_batch,

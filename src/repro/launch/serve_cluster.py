@@ -21,6 +21,7 @@ from repro.cluster import RecoveryPolicy, build_sim_cluster
 from repro.common.faults import FaultPlan
 from repro.configs import get_config
 from repro.core.latency_model import DEVICES
+from repro.launch.compile_cache import configure_compile_cache
 from repro.serving import DATASETS, Tracer, make_trace
 
 
@@ -177,6 +178,7 @@ def main():
                          "many seconds to every request (deadline-based "
                          "load shedding)")
     args = ap.parse_args()
+    configure_compile_cache()
 
     profile = DATASETS[args.dataset]
     tracer = Tracer() if args.trace else None

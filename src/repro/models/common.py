@@ -142,10 +142,10 @@ class KeyGen:
     """Splittable RNG stream."""
 
     def __init__(self, key):
-        self._key = key
+        self.key = key
 
     def __call__(self):
-        self._key, sub = jax.random.split(self._key)
+        self.key, sub = jax.random.split(self.key)
         return sub
 
 

@@ -159,14 +159,14 @@ def moe_block_sharded(params, cfg: ArchConfig, x, *, model_axis="model"):
     da_key = da if len(da) != 1 else da[0]
     body = functools.partial(_moe_local, cfg=cfg, n_model=n_model,
                              model_axis=model_axis)
-    from repro.distributed.collectives import shard_map_compat
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(da_key, None), P(None, None),
                   P(model_axis, None, None), P(model_axis, None, None),
                   P(model_axis, None, None)),
         out_specs=P(da_key, None),
+        check_vma=False,
     )
     out = fn(xf, params["router"], params["w_gate"], params["w_up"],
              params["w_down"])
@@ -238,14 +238,14 @@ def moe_block_2d(params, cfg: ArchConfig, x, *, model_axis="model"):
     da_key = da if len(da) != 1 else da[0]
     body = functools.partial(_moe_local_2d, cfg=cfg, model_axis=model_axis,
                              data_axes=da)
-    from repro.distributed.collectives import shard_map_compat
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(None, None), P(None, None),
                   P(model_axis, None, da_key), P(model_axis, None, da_key),
                   P(model_axis, da_key, None)),
         out_specs=P(None, None),
+        check_vma=False,
     )
     out = fn(xf, params["router"], params["w_gate"], params["w_up"],
              params["w_down"])

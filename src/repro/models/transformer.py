@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from repro.distributed.sharding import shard
 from repro.kernels import ref as kernel_ref
 from repro.kernels.chunked_paged_attn import paged_chunk_attention_kernel
+from repro.kernels.ops import resolve_interpret
 from repro.models import ssm
 from repro.models.common import (ArchConfig, KeyGen, dense_init_a,
                                  embed_init_a)
@@ -54,13 +55,25 @@ from repro.models.moe import axes_moe, init_moe, moe_block
 
 
 def _stack_init(init_fn, kg, cfg, n, abstract):
-    """Initialize ``n`` stacked copies of a param subtree (leading dim n)."""
+    """Initialize ``n`` stacked copies of a param subtree (leading dim n).
+
+    The copies come out of one ``lax.scan`` that threads the key stream
+    layer by layer (the same draws as ``n`` sequential inits), so under
+    ``jax.jit(model.init)`` the stack is written in place — no list of
+    per-layer copies beside it — and the body compiles once, not ``n``
+    times."""
     if abstract:
         one = init_fn(kg, cfg, abstract=True)
         return jax.tree.map(
             lambda s: jax.ShapeDtypeStruct((n,) + tuple(s.shape), s.dtype), one)
-    subs = [init_fn(kg, cfg, abstract=False) for _ in range(n)]
-    return jax.tree.map(lambda *xs: jnp.stack(xs), *subs)
+
+    def body(key, _):
+        layer_kg = KeyGen(key)
+        sub = init_fn(layer_kg, cfg, abstract=False)
+        return layer_kg.key, sub
+
+    kg.key, stacked = jax.lax.scan(body, kg.key, None, length=n)
+    return stacked
 
 
 def _stack_axes(axes_fn, cfg):
@@ -299,14 +312,14 @@ class TransformerLM:
                 kp = lx["page_k"].astype(cfg.cdt)
                 vp = lx["page_v"].astype(cfg.cdt)
                 ks = shared.get("kv_shard")
+                interpret = resolve_interpret(shared["paged_interpret"])
                 if ks is not None:
                     from repro.distributed.collectives import \
                         split_kv_paged_partial
                     parts.append(split_kv_paged_partial(
                         q, kp, vp, shared["block_tables"],
                         shared["ctx_lens"], shared["shard_offs"], ks,
-                        impl=shared["paged_impl"],
-                        interpret=shared["paged_interpret"]))
+                        impl=shared["paged_impl"], interpret=interpret))
                 elif shared["paged_impl"] == "ref":
                     parts.append(kernel_ref.paged_chunk_ref(
                         q, kp, vp, shared["block_tables"],
@@ -314,8 +327,7 @@ class TransformerLM:
                 else:
                     parts.append(paged_chunk_attention_kernel(
                         q, kp, vp, shared["block_tables"],
-                        shared["ctx_lens"],
-                        interpret=shared["paged_interpret"]))
+                        shared["ctx_lens"], interpret=interpret))
             if "self_flash" in shared:
                 sf = shared["self_flash"]
                 B, T = pos1d.shape
@@ -726,8 +738,6 @@ class TransformerLM:
         positions = offsets[:, None] + offs[None, :]
         validm = offs[None, :] < valid[:, None]
         shared = self._window_masks(cache, positions, validm, T)
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         shared.update(block_tables=block_tables.astype(jnp.int32),
                       ctx_lens=offsets.astype(jnp.int32),
                       paged_impl=impl, paged_interpret=interpret)
@@ -783,8 +793,6 @@ class TransformerLM:
         positions = win_start[:, None] + offs[None, :]
         valid = offs[None, :] < win_valid[:, None]
         shared = self._window_masks(cache, positions, valid, c)
-        if interpret is None:
-            interpret = jax.default_backend() != "tpu"
         shared.update(block_tables=block_tables.astype(jnp.int32),
                       ctx_lens=ctx_lens.astype(jnp.int32),
                       paged_impl=impl, paged_interpret=interpret)

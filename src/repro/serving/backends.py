@@ -37,8 +37,8 @@ from repro.core.chunked import (ChunkedDecodeState, batch_apply_step,
                                 batch_windows, freeze_run)
 from repro.core.diffusion import softmax_confidence
 from repro.core.latency_model import (AnalyticDeviceModel, DeviceSpec,
-                                      TPU_V5E, kv_bytes_per_token,
-                                      swap_cost_s)
+                                      TPU_V5E, device_spec,
+                                      kv_bytes_per_token, swap_cost_s)
 from repro.models.common import ArchConfig
 from repro.serving.kv_pool import OutOfPages, PagedKVAllocator
 from repro.serving.request import Request
@@ -816,6 +816,42 @@ class SimBackend:
 # Real-model backend
 # ===========================================================================
 
+# HBM left free beside a default-sized page pool, for the step programs'
+# temporaries and allocator slack.  At SDAR-8B width on v5e the compiled
+# decode step at B=32, c=32 needs ~0.93 GB of temporaries (mostly the
+# [B, c, V] fp32 logits) and a 256-token prefill chunk ~0.36 GB
+# (``compiled.memory_analysis()``).
+POOL_HEADROOM_BYTES = 3 << 30
+
+
+def hbm_pool_pages(model, *, page_size: int | None = None,
+                   kv_shards: int = 1, replicas: int = 1) -> int | None:
+    """Pages for each of ``replicas`` page pools of ``model`` (compute
+    dtype) that share the first ``kv_shards`` devices: the HBM each device
+    has left after what is resident now (the weights), less
+    :data:`POOL_HEADROOM_BYTES`, split evenly between the replicas, with
+    one equal slice of each pool per device.  None when a device reports
+    no memory limit (the host CPU).  Whoever owns the devices calls this
+    once, before building any of the pools it sizes."""
+    import jax
+    L, KVH, hd = model.paged_kv_dims()
+    ps = page_size if page_size is not None else model.cfg.kv_page_size
+    page_bytes = 2 * L * ps * KVH * hd * model.cfg.cdt.itemsize
+    free = []
+    for d in jax.devices()[:kv_shards]:
+        stats = d.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            return None
+        free.append(stats["bytes_limit"] - stats["bytes_in_use"])
+    per_device = (min(free) - POOL_HEADROOM_BYTES) // replicas // page_bytes
+    if per_device <= 0:
+        raise ValueError(
+            f"no HBM left for {replicas} KV pool(s): {min(free)} B free per "
+            f"device, {POOL_HEADROOM_BYTES} B headroom — serve fewer layers "
+            f"or replicas")
+    return int(per_device) * kv_shards
+
+
 class ModelBackend:
     """Real-model backend (decoder-only families).
 
@@ -858,7 +894,7 @@ class ModelBackend:
 
     def __init__(self, model, params, n_slots: int = 8, max_len: int = 512,
                  decode_mode: str = "elastic", obs: bool = False,
-                 cache_dtype=np.float32, paged: bool | None = None,
+                 cache_dtype=None, paged: bool | None = None,
                  kv_pages: int | None = None, page_size: int | None = None,
                  attn_impl: str | None = None, interpret: bool | None = None,
                  prefill_mode: str = "chunked",
@@ -897,13 +933,16 @@ class ModelBackend:
         self.kv_shards = kv_shards
         self.prefill_tokens_history: list[int] = []  # prompt tokens per tick
         self.last_prefill_plan: list[tuple[int, int, int]] = []
+        # KV is stored in the compute dtype: the attention reads it as-is
+        cache_dtype = self.cfg.cdt if cache_dtype is None else cache_dtype
 
         if self.paged:
             model._check_paged()
             ps = page_size if page_size is not None else self.cfg.kv_page_size
             if kv_pages is None:
-                # mirror the historical dense cache's capacity by default so
-                # sizing stays comparable across releases
+                # mirror the historical dense cache's n_slots × max_len;
+                # callers that own the devices size the pool from HBM
+                # with hbm_pool_pages
                 kv_pages = n_slots * (-(-max_len // ps))
             # sharded pool: pages split evenly across shards
             kv_pages = -(-kv_pages // kv_shards) * kv_shards
@@ -930,10 +969,9 @@ class ModelBackend:
                                      dtype=cache_dtype)
             self._table_width = self.kv.pages_for(max_len)
             self._n_attn_layers = model.paged_kv_dims()[0]
-            # cost-model stand-in for swap-vs-recompute and adaptive
-            # prefill sizing (the model path runs on the host)
-            from repro.core.latency_model import CPU_HOST
-            self._analytic = AnalyticDeviceModel(self.cfg, CPU_HOST)
+            # cost model of the device this process serves on, for
+            # swap-vs-recompute and adaptive prefill sizing
+            self._analytic = AnalyticDeviceModel(self.cfg, device_spec())
             align = _prefill_align(ps, self.cfg)
             target_bc = None
             if prefill_token_budget is None and prefill_mode == "chunked":

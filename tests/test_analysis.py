@@ -178,7 +178,6 @@ def test_hlo006_registration_fires_when_unregistered(monkeypatch):
 
 def _cost_bytes(fn, *args) -> float:
     ca = jax.jit(fn).lower(*args).compile().cost_analysis()
-    ca = ca[0] if isinstance(ca, (list, tuple)) else ca
     return float(ca["bytes accessed"])
 
 

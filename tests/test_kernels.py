@@ -25,6 +25,7 @@ PAGED_SHAPES = [
     (2, 16, 8, 2, 128, 16, 4),
     (3, 32, 6, 3, 64, 8, 16),
     (2, 1, 4, 4, 128, 32, 2),     # MHA, AR-style single query
+    (1, 80, 8, 2, 64, 16, 8),     # 320 rows per KV head: two row tiles
 ]
 
 

@@ -5,6 +5,8 @@ parity.  The backend's dense-slot decode path for attention families was
 retired — goldens come from the model-level dense cache (still used for
 training/prefill) and teacher-forced replay, not from a dense backend."""
 
+import gc
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -357,6 +359,43 @@ def test_build_model_cluster_serves_paged_replicas(model_and_params):
     assert not rep.rejected
     for core in cluster.replicas:
         assert core.backend.kv.free_pages == core.backend.kv.n_pages
+
+
+class _HBMDevice:
+    """A device that reports an HBM limit the way a TPU's memory_stats()
+    does, with the bytes in use read from the live arrays."""
+
+    def __init__(self, device, limit):
+        self._device, self._limit = device, limit
+
+    def __getattr__(self, name):
+        return getattr(self._device, name)
+
+    def memory_stats(self):
+        return {"bytes_limit": self._limit,
+                "bytes_in_use": sum(a.nbytes for a in jax.live_arrays())}
+
+
+def test_build_model_cluster_splits_hbm_between_replicas(model_and_params,
+                                                         monkeypatch):
+    """Replicas on one device split the HBM left after the weights; the
+    first one built does not take it all."""
+    from repro.serving.backends import POOL_HEADROOM_BYTES
+    model, params = model_and_params
+    page_bytes = 2 * CFG.n_layers * CFG.kv_page_size * CFG.n_kv_heads \
+        * CFG.hd * CFG.cdt.itemsize
+    gc.collect()
+    in_use = sum(a.nbytes for a in jax.live_arrays())
+    limit = in_use + POOL_HEADROOM_BYTES + 2 * 40 * page_bytes + page_bytes
+    dev = _HBMDevice(jax.devices()[0], limit)
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [dev])
+    cluster = build_model_cluster(model, params, 2, "round_robin",
+                                  profile=PROF, mode="bd8", max_len=64,
+                                  max_batch=4)
+    assert [core.backend.kv.n_pages for core in cluster.replicas] == [40, 40]
+    assert dev.memory_stats()["bytes_in_use"] <= limit - POOL_HEADROOM_BYTES
+    rep = cluster.run(_requests(4, simultaneous=True))
+    assert all(m.n_tokens == 16 for m in rep.metrics)
 
 
 def test_fits_ever_respects_model_max_len(model_and_params):

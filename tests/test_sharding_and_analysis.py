@@ -74,9 +74,7 @@ def test_hlo_analyzer_trip_counts():
     expected = 2 * 64 * 64 * 64 * 30
     assert abs(res["flops"] - expected) / expected < 0.01
     # xla's own cost analysis undercounts by the trip count
-    # (newer jax returns a single dict, older a one-element list)
-    ca = comp.cost_analysis()
-    xla = (ca[0] if isinstance(ca, (list, tuple)) else ca)["flops"]
+    xla = comp.cost_analysis()["flops"]
     assert res["flops"] > 10 * xla
     # traffic: w is consumed via per-step dynamic-slice → ≈ read once overall
     w_bytes = 30 * 64 * 64 * 4
