@@ -1,0 +1,118 @@
+"""What a cell is, read from ``BENCHMARK.json`` and the files it names.
+
+Everything that belongs to one configuration, one traffic mix or one
+per-layer metric sits in a file of its own and is found by its name:
+
+* a configuration: the ``file`` its ``configs`` entry gives (JSON);
+* a traffic mix: ``bench/traffic/<traffic>.json``;
+* a per-layer metric: ``bench/metrics/<name>.py``, a module with
+  ``read(run) -> float | None``.
+
+So a new cell, configuration or metric is new files and entries, never an
+edit of the harness.
+"""
+
+from __future__ import annotations
+
+import importlib.util
+import json
+from dataclasses import dataclass
+from pathlib import Path
+
+BENCH_DIR = Path(__file__).resolve().parent
+ROOT = BENCH_DIR.parent
+
+
+def load_benchmark(root: Path = ROOT) -> dict:
+    return json.loads((root / "BENCHMARK.json").read_text())
+
+
+def load_json(path: Path) -> dict:
+    return json.loads(Path(path).read_text())
+
+
+@dataclass
+class Cell:
+    name: str
+    chips: int
+    config: dict            # the configuration file's contents
+    traffic: dict           # the traffic file's contents
+    end_to_end: list        # BENCHMARK.json entries this cell reports
+    per_layer: list
+
+
+def _applies(metric: dict, cell: str, e2e_names: set | None = None) -> bool:
+    if "workloads" in metric:
+        return cell in metric["workloads"]
+    return e2e_names is None or metric["moves"] in e2e_names
+
+
+def resolve_cell(name: str, bench: dict | None = None,
+                 bench_dir: Path = BENCH_DIR, root: Path = ROOT) -> Cell:
+    bench = load_benchmark(root) if bench is None else bench
+    try:
+        w = next(w for w in bench["workloads"] if w["name"] == name)
+    except StopIteration:
+        raise SystemExit(f"no workload {name!r} in BENCHMARK.json; cells: "
+                         + ", ".join(w["name"] for w in bench["workloads"]))
+    conf = next(c for c in bench["configs"] if c["name"] == w["config"])
+    e2e = [m for m in bench["end_to_end"] if _applies(m, name)]
+    names = {m["name"] for m in e2e}
+    per_layer = [m for m in bench["per_layer"] if _applies(m, name, names)]
+    return Cell(name=name, chips=int(w["chips"]),
+                config=load_json(root / conf["file"]),
+                traffic=load_json(bench_dir / "traffic"
+                                  / f"{w['traffic']}.json"),
+                end_to_end=e2e, per_layer=per_layer)
+
+
+def metric_reader(name: str, bench_dir: Path = BENCH_DIR):
+    """The ``read`` function of ``bench/metrics/<name>.py``."""
+    path = bench_dir / "metrics" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(
+        f"bench_metric_{name.replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+def list_cells(bench: dict | None = None) -> list[str]:
+    bench = load_benchmark() if bench is None else bench
+    return [w["name"] for w in bench["workloads"]]
+
+
+# -- configuration file → the program's ArchConfig ---------------------------
+
+# published (Hugging Face config.json) key → ArchConfig field
+_HF_KEYS = {
+    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
+    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
+    "head_dim": "head_dim", "intermediate_size": "d_ff",
+    "vocab_size": "vocab_size", "rope_theta": "rope_theta",
+    "rms_norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings",
+}
+_SERVING_KEYS = ("block_size", "confidence_threshold", "mask_token_id",
+                 "kv_page_size")
+
+
+def model_spec(config: dict, smoke: bool = False) -> dict:
+    """The numbers the model is run with: the published keys, the serving
+    keys and the logit gain, with the file's ``smoke`` overrides applied
+    for a CPU rehearsal."""
+    spec = {k: config[k] for k in (*_HF_KEYS, *_SERVING_KEYS)}
+    spec["dtype"] = config["torch_dtype"]
+    spec["hidden_act"] = config["hidden_act"]
+    spec["logit_gain"] = float(config["logit_gain"])
+    if smoke:
+        spec.update(config["smoke"])
+    return spec
+
+
+def arch_kwargs(spec: dict) -> dict:
+    """``ArchConfig`` keyword arguments for ``spec``."""
+    kw = {field: spec[k] for k, field in _HF_KEYS.items()}
+    kw.update({k: spec[k] for k in _SERVING_KEYS})
+    kw.update(name="bench", family="dense", act=spec["hidden_act"],
+              param_dtype=spec["dtype"], compute_dtype=spec["dtype"],
+              paged_attn_impl="kernel", remat=False)
+    return kw
