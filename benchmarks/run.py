@@ -606,23 +606,6 @@ def fault_tolerance(quick=False):
          f"{s['migrations']} migrations")
 
 
-def telemetry(quick=False):
-    """Tracer overhead: traced vs untraced cluster sweep cells →
-    BENCH_telemetry.json (see benchmarks/telemetry_overhead)."""
-    from benchmarks.telemetry_overhead import run_sweep
-    payload = run_sweep(quick=quick, verbose=False)
-    s = payload["summary"]
-    emit("telemetry.enabled_overhead_worst",
-         f"{s['enabled_overhead_worst']*100:.2f}%",
-         f"<5% target met: {s['enabled_under_5pct']}")
-    emit("telemetry.disabled_overhead_worst",
-         f"{s['disabled_overhead_worst']*100:.4f}%",
-         f"<2% target met: {s['disabled_under_2pct']}; "
-         f"null call {payload['null_call_cost_ns']:.0f} ns")
-    emit("telemetry.reports_match", str(s["all_reports_match"]).lower(),
-         "traced and untraced runs bit-identical")
-
-
 ALL = {
     "table2": table2_profiles,
     "fig1": fig1_load_sensitivity,
@@ -642,7 +625,6 @@ ALL = {
     "decode_step": decode_step,
     "split_kv": split_kv,
     "prefill_interleave": prefill_interleave,
-    "telemetry": telemetry,
     "kv_reuse": kv_reuse,
     "fault_tolerance": fault_tolerance,
 }

@@ -240,11 +240,12 @@ def _is_jax_jit(node) -> bool:
 
 
 def _root_callee(node) -> str:
-    """Descriptor of the callable being jitted: unwrap functools.partial,
-    name lambdas, unparse dotted names."""
+    """Descriptor of the callable being jitted: unwrap functools.partial
+    (and ``named_partial``), name lambdas, unparse dotted names."""
     if isinstance(node, ast.Call):
         fn = node.func
-        is_partial = (isinstance(fn, ast.Name) and fn.id == "partial") or \
+        is_partial = (isinstance(fn, ast.Name)
+                      and fn.id in ("partial", "named_partial")) or \
             (isinstance(fn, ast.Attribute) and fn.attr == "partial")
         if is_partial and node.args:
             return _root_callee(node.args[0])

@@ -164,6 +164,7 @@ def paged_chunk_attention_kernel(q, k_pages, v_pages, block_tables, ctx_lens,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="chunked_paged_attn",
     )(block_tables, ctx_lens, qg, k_pages, v_pages)
 
     # ungroup: [B, KVH, G, c, D] → [B, c, H, D] partials

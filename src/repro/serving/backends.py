@@ -29,6 +29,7 @@ retry the step.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -42,7 +43,28 @@ from repro.core.latency_model import (AnalyticDeviceModel, DeviceSpec,
 from repro.models.common import ArchConfig
 from repro.serving.kv_pool import OutOfPages, PagedKVAllocator
 from repro.serving.request import Request
+from repro.serving.telemetry import span
 from repro.serving.workload import CommitSimulator
+
+
+def named_partial(fn, **kw):
+    """``functools.partial(fn, **kw)`` under ``fn``'s name, so that
+    ``jax.jit`` names the program after ``fn`` (``jit_<name>``) in HLO
+    dumps, compile logs and profiler traces, not ``jit__unknown``."""
+    p = functools.partial(fn, **kw)
+    p.__name__ = fn.__name__
+    return p
+
+
+class PrefillPlan(list):
+    """One prefill dispatch: its ``(rid, offset, n)`` entries, and the
+    padded shape the program ran it at (``padded_rows`` ×
+    ``padded_len`` token slots)."""
+
+    def __init__(self, entries, padded_rows: int, padded_len: int):
+        super().__init__(entries)
+        self.padded_rows = padded_rows
+        self.padded_len = padded_len
 
 
 @dataclass
@@ -901,8 +923,6 @@ class ModelBackend:
                  prefill_token_budget: int | None = None,
                  kv_shards: int = 1, prefix_cache: bool = True,
                  host_kv_pages: int = 0):
-        import functools
-
         import jax
         import jax.numpy as jnp
         if prefill_mode not in ("chunked", "wave"):
@@ -1000,14 +1020,14 @@ class ModelBackend:
             # stale outside reference raises on use ("Array has been
             # deleted") rather than reading freed memory.
             self._prefill_paged = jax.jit(
-                functools.partial(model.prefill_paged, head_mode="sample",
-                                  kv_shard=self._kv_shard),
+                named_partial(model.prefill_paged, head_mode="sample",
+                              kv_shard=self._kv_shard),
                 donate_argnums=(1,))
-            self._prefill_chunk = jax.jit(functools.partial(
+            self._prefill_chunk = jax.jit(named_partial(
                 model.prefill_chunk_paged, impl=impl, interpret=interpret,
                 kv_shard=self._kv_shard),
                 donate_argnums=(1,))
-            self._decode_paged = jax.jit(functools.partial(
+            self._decode_paged = jax.jit(named_partial(
                 model.decode_step_paged, impl=impl, interpret=interpret,
                 kv_shard=self._kv_shard),
                 donate_argnums=(1,))
@@ -1398,7 +1418,8 @@ class ModelBackend:
                 st.commit(int(tok[i]))
                 fresh.add(r.rid)
         self.prefill_tokens_history.append(sum(r.prompt_len for r in reqs))
-        self.last_prefill_plan = [(r.rid, 0, r.prompt_len) for r in reqs]
+        self.last_prefill_plan = PrefillPlan(
+            [(r.rid, 0, r.prompt_len) for r in reqs], Bp, Tp)
         return fresh
 
     def _chunked_prefill_tick(self, live_bc: int = 0) -> set:
@@ -1406,57 +1427,66 @@ class ModelBackend:
         prompt tokens of prefill cursors (FCFS, page-aligned chunk ends).
         Returns rids whose prompt completed this tick AND received their
         prefill-derived first token (AR)."""
-        plan = self._prefill.plan(live_bc)
+        with span("serving.backend.prefill.plan"):
+            plan = self._prefill.plan(live_bc)
+            if plan and self.prefix_cache:
+                # COW before the chunk scatter can land in a shared page
+                # (the AR last-prompt-token re-prefill after a
+                # full-coverage hit)
+                for req, off, n in plan:
+                    self.kv.ensure_private(req.rid, off, off + n)
         if not plan:
             return set()
-        if self.prefix_cache:
-            # COW before the chunk scatter can land in a shared page (the
-            # AR last-prompt-token re-prefill after a full-coverage hit)
-            for req, off, n in plan:
-                self.kv.ensure_private(req.rid, off, off + n)
         jnp = self.jnp
         B = len(plan)
         Bp = self._bucket(B)
         Tp = self._bucket(max(n for _, _, n in plan))
-        toks = np.zeros((Bp, Tp), np.int32)
-        offs = np.zeros(Bp, np.int64)
-        val = np.zeros(Bp, np.int64)
-        tables = np.zeros((Bp, self._table_width), np.int32)
-        tables[:B] = self.kv.batch_tables([req.rid for req, _, _ in plan],
-                                          self._table_width)
-        for i, (req, off, n) in enumerate(plan):
-            toks[i, :n] = np.asarray(req.prompt_tokens[off:off + n],
-                                     np.int32)
-            offs[i] = off
-            val[i] = n
-        kw = {}
-        if self._kv_shard is not None:
-            kw["shard_offs"] = jnp.asarray(self._stripe_offs(
-                [req.rid for req, _, _ in plan], Bp))
-        conf, tok, pages = self._prefill_chunk(
-            self.params, self._pages_cache(), jnp.asarray(toks),
-            jnp.asarray(offs, jnp.int32), jnp.asarray(val, jnp.int32),
-            jnp.asarray(tables), **kw)
-        self._store_pages(pages)
-        self.prefill_dispatches += 1
-        self._account_device_dispatch(Bp, Tp)
-        conf = np.asarray(conf)
-        tok = np.asarray(tok)
-        self.host_transfer_bytes += conf.nbytes + tok.nbytes
-        fresh = set()
-        for i, (req, off, n) in enumerate(plan):
-            if self._prefill.advance(req.rid, n):
-                st = self._states[req.rid]
-                if isinstance(st, ARState):
-                    st.commit(int(tok[i]))
-                    fresh.add(req.rid)
-                if self.prefix_cache:
-                    # the prompt's pages now hold exactly the KV a fresh
-                    # prefill would write — index them for reuse
-                    self.kv.register_prefix(req.rid, req.prompt_tokens,
-                                            limit=req.prompt_len)
-        self.prefill_tokens_history.append(sum(n for _, _, n in plan))
-        self.last_prefill_plan = [(req.rid, off, n) for req, off, n in plan]
+        n_tokens = sum(n for _, _, n in plan)
+        with span("serving.backend.prefill.pack"):
+            toks = np.zeros((Bp, Tp), np.int32)
+            offs = np.zeros(Bp, np.int64)
+            val = np.zeros(Bp, np.int64)
+            tables = np.zeros((Bp, self._table_width), np.int32)
+            tables[:B] = self.kv.batch_tables(
+                [req.rid for req, _, _ in plan], self._table_width)
+            for i, (req, off, n) in enumerate(plan):
+                toks[i, :n] = np.asarray(req.prompt_tokens[off:off + n],
+                                         np.int32)
+                offs[i] = off
+                val[i] = n
+            kw = {}
+            if self._kv_shard is not None:
+                kw["shard_offs"] = jnp.asarray(self._stripe_offs(
+                    [req.rid for req, _, _ in plan], Bp))
+            args = (self.params, self._pages_cache(), jnp.asarray(toks),
+                    jnp.asarray(offs, jnp.int32), jnp.asarray(val, jnp.int32),
+                    jnp.asarray(tables))
+        with span("serving.backend.prefill.launch", rows=B, padded_rows=Bp,
+                  tokens=n_tokens, padded_tokens=Bp * Tp):
+            conf, tok, pages = self._prefill_chunk(*args, **kw)
+            self._store_pages(pages)
+            self.prefill_dispatches += 1
+            self._account_device_dispatch(Bp, Tp)
+        with span("serving.backend.prefill.wait"):
+            conf = np.asarray(conf)
+            tok = np.asarray(tok)
+        with span("serving.backend.prefill.commit"):
+            self.host_transfer_bytes += conf.nbytes + tok.nbytes
+            fresh = set()
+            for i, (req, off, n) in enumerate(plan):
+                if self._prefill.advance(req.rid, n):
+                    st = self._states[req.rid]
+                    if isinstance(st, ARState):
+                        st.commit(int(tok[i]))
+                        fresh.add(req.rid)
+                    if self.prefix_cache:
+                        # the prompt's pages now hold exactly the KV a
+                        # fresh prefill would write — index them for reuse
+                        self.kv.register_prefix(req.rid, req.prompt_tokens,
+                                                limit=req.prompt_len)
+            self.prefill_tokens_history.append(n_tokens)
+            self.last_prefill_plan = PrefillPlan(
+                [(req.rid, off, n) for req, off, n in plan], Bp, Tp)
         return fresh
 
     def _prefill_tick(self, live_bc: int = 0) -> set:
@@ -1510,30 +1540,34 @@ class ModelBackend:
         jnp = self.jnp
         B, c = win.shape
         Bp = self._bucket(B)
-        tables = np.zeros((Bp, self._table_width), np.int32)
-        tables[:B] = self.kv.batch_tables(rids, self._table_width)
-        w = np.full((Bp, c), self.cfg.mask_token_id, np.int64)
-        w[:B] = win
-        s = np.zeros(Bp, np.int64)
-        s[:B] = start
-        v = np.zeros(Bp, np.int64)
-        v[:B] = valid
-        a = np.zeros(Bp, np.int64)
-        a[:B] = n_adv
-        cache = self._pages_cache()
-        args = (self.params, cache, jnp.asarray(w, jnp.int32),
-                jnp.asarray(s, jnp.int32), jnp.asarray(v, jnp.int32),
-                jnp.asarray(tables), jnp.asarray(s, jnp.int32),
-                jnp.asarray(a, jnp.int32))
-        kw = {}
-        if self._kv_shard is not None:
-            kw["shard_offs"] = jnp.asarray(self._stripe_offs(rids, Bp))
-        conf, tok, pages = self._decode_paged(*args, **kw)
-        self._store_pages(pages)
-        self.decode_dispatches += 1
-        self._account_device_dispatch(Bp, c)
-        conf = np.asarray(conf)
-        tok = np.asarray(tok)
+        with span("serving.backend.decode.pack"):
+            tables = np.zeros((Bp, self._table_width), np.int32)
+            tables[:B] = self.kv.batch_tables(rids, self._table_width)
+            w = np.full((Bp, c), self.cfg.mask_token_id, np.int64)
+            w[:B] = win
+            s = np.zeros(Bp, np.int64)
+            s[:B] = start
+            v = np.zeros(Bp, np.int64)
+            v[:B] = valid
+            a = np.zeros(Bp, np.int64)
+            a[:B] = n_adv
+            cache = self._pages_cache()
+            args = (self.params, cache, jnp.asarray(w, jnp.int32),
+                    jnp.asarray(s, jnp.int32), jnp.asarray(v, jnp.int32),
+                    jnp.asarray(tables), jnp.asarray(s, jnp.int32),
+                    jnp.asarray(a, jnp.int32))
+            kw = {}
+            if self._kv_shard is not None:
+                kw["shard_offs"] = jnp.asarray(self._stripe_offs(rids, Bp))
+        with span("serving.backend.decode.launch", rows=B, padded_rows=Bp,
+                  chunk=c, valid=int(v.sum())):
+            conf, tok, pages = self._decode_paged(*args, **kw)
+            self._store_pages(pages)
+            self.decode_dispatches += 1
+            self._account_device_dispatch(Bp, c)
+        with span("serving.backend.decode.wait"):
+            conf = np.asarray(conf)
+            tok = np.asarray(tok)
         self.host_transfer_bytes += conf.nbytes + tok.nbytes
         return conf[:B], tok[:B].astype(np.int64)
 
@@ -1541,35 +1575,42 @@ class ModelBackend:
         """AR decode over the page pool: c=1 window at the last committed
         token, prefix = everything before it; the input token's KV freezes
         into the pool every step (n_adv = 1)."""
-        states = [self._states[rid] for rid in ar_rids]
-        B = len(states)
-        win = np.empty((B, 1), np.int64)
-        start = np.empty(B, np.int64)
-        for i, st in enumerate(states):
-            win[i, 0] = st.committed[st.frozen - 1]
-            start[i] = st.prompt_len + st.frozen - 1
-        ones = np.ones(B, np.int64)
+        with span("serving.backend.decode.assemble"):
+            states = [self._states[rid] for rid in ar_rids]
+            B = len(states)
+            win = np.empty((B, 1), np.int64)
+            start = np.empty(B, np.int64)
+            for i, st in enumerate(states):
+                win[i, 0] = st.committed[st.frozen - 1]
+                start[i] = st.prompt_len + st.frozen - 1
+            ones = np.ones(B, np.int64)
         _, tok = self._dispatch_window(ar_rids, win, start, ones, ones)
-        for i, (rid, st) in enumerate(zip(ar_rids, states)):
-            st.commit(int(tok[i, 0]))
-            infos[rid] = StepInfo(1, np.ones(1, bool), 1, st.done)
+        with span("serving.backend.decode.commit", committed=B):
+            for i, (rid, st) in enumerate(zip(ar_rids, states)):
+                st.commit(int(tok[i, 0]))
+                infos[rid] = StepInfo(1, np.ones(1, bool), 1, st.done)
 
     def _step_diffusion_paged(self, diff_rids, chunk, infos):
-        states = [self._states[rid] for rid in diff_rids]
-        win, start, valid, cai = batch_windows(states, chunk)
-        # the freeze run is known before the step (leading committed-at-
-        # input positions) — this is what makes the fused freeze possible
-        n_adv = freeze_run(valid, cai)
+        with span("serving.backend.decode.assemble"):
+            states = [self._states[rid] for rid in diff_rids]
+            win, start, valid, cai = batch_windows(states, chunk)
+            # the freeze run is known before the step (leading
+            # committed-at-input positions) — this is what makes the
+            # fused freeze possible
+            n_adv = freeze_run(valid, cai)
         conf, tok = self._dispatch_window(diff_rids, win, start, valid,
                                           n_adv)
-        commit, n_adv_post = batch_apply_step(states, conf, tok, valid, cai)
-        # invariant: commits this step can never clamp the pre-step run
-        # (the fused dispatch already froze n_adv entries into the pool)
-        assert (n_adv_post == n_adv).all(), (n_adv_post, n_adv)
-        for i, (rid, st) in enumerate(zip(diff_rids, states)):
-            st.advance(int(n_adv_post[i]))
-            infos[rid] = StepInfo(int(commit[i].sum()), commit[i],
-                                  int(valid[i]), st.done)
+        with span("serving.backend.decode.commit") as sp:
+            commit, n_adv_post = batch_apply_step(states, conf, tok, valid,
+                                                  cai)
+            # invariant: commits this step can never clamp the pre-step run
+            # (the fused dispatch already froze n_adv entries into the pool)
+            assert (n_adv_post == n_adv).all(), (n_adv_post, n_adv)
+            for i, (rid, st) in enumerate(zip(diff_rids, states)):
+                st.advance(int(n_adv_post[i]))
+                infos[rid] = StepInfo(int(commit[i].sum()), commit[i],
+                                      int(valid[i]), st.done)
+            sp.set_metadata(committed=int(commit.sum()))
 
     def _split_ar(self, rids, infos):
         """Partition rids into (live AR, diffusion); AR requests already
@@ -1589,50 +1630,59 @@ class ModelBackend:
     def decode_step(self, rids, chunk: int):
         infos: dict[int, StepInfo] = {}
         if self.paged:
-            live_b = sum(1 for r in rids if not self._prefill.pending(r))
-            fresh = self._prefill_tick(live_b * chunk)
-            # requests whose prompt is still mid-prefill sit this decode
-            # dispatch out; ones whose last chunk just landed join it
-            ready = [r for r in rids if not self._prefill.pending(r)]
-            ar_rids, diff_rids = self._split_ar(ready, infos)
-            live = ar_rids + diff_rids
-            if self.prefix_cache:
-                # decode writes land past the committed frontier; COW any
-                # shared page the worst-case window can touch before the
-                # donated scatter mutates the pool in place
-                for r in live:
-                    st = self._states[r]
-                    if st.done:
-                        continue
-                    lo = st.prompt_len + st.frozen
-                    if isinstance(st, ARState):
-                        lo -= 1
-                    self.kv.ensure_private(r, lo, _worst_step_len(st, chunk))
-            if live:
-                # worst-case page reservation; transactional OutOfPages
-                # (nothing mutated yet) lets the engine preempt + retry
-                _reserve_step(self.kv, self._states, live, chunk)
-            if ar_rids:
-                self._step_ar_paged(ar_rids, infos)
-            if diff_rids:
-                self._step_diffusion_paged(diff_rids, chunk, infos)
-            if live:
-                _trim_step(self.kv, self._states, live)
-            for r in rids:                    # still-prefilling: idle info
-                if r not in infos:
-                    infos[r] = StepInfo(0, np.zeros(1, bool), 0, False)
-            for r in fresh:
-                # surface the prefill-derived AR first token in this tick's
-                # StepInfo so the engine stamps TTFT at the tick the last
-                # chunk completed (valid_len stays untouched: prefill
-                # commits don't feed the TU estimator)
-                fi = infos.get(r)
-                if fi is None:
-                    infos[r] = StepInfo(1, np.ones(1, bool), 0,
-                                        self._states[r].done)
-                else:
-                    infos[r] = StepInfo(fi.n_committed + 1, fi.commit_mask,
-                                        fi.valid_len, fi.done)
+            with span("serving.backend.step"):
+                live_b = sum(1 for r in rids
+                             if not self._prefill.pending(r))
+                fresh = self._prefill_tick(live_b * chunk)
+                # requests whose prompt is still mid-prefill sit this
+                # decode dispatch out; ones whose last chunk just landed
+                # join it
+                ready = [r for r in rids if not self._prefill.pending(r)]
+                ar_rids, diff_rids = self._split_ar(ready, infos)
+                live = ar_rids + diff_rids
+                with span("serving.backend.decode.reserve"):
+                    if self.prefix_cache:
+                        # decode writes land past the committed frontier;
+                        # COW any shared page the worst-case window can
+                        # touch before the donated scatter mutates the
+                        # pool in place
+                        for r in live:
+                            st = self._states[r]
+                            if st.done:
+                                continue
+                            lo = st.prompt_len + st.frozen
+                            if isinstance(st, ARState):
+                                lo -= 1
+                            self.kv.ensure_private(
+                                r, lo, _worst_step_len(st, chunk))
+                    if live:
+                        # worst-case page reservation; transactional
+                        # OutOfPages (nothing mutated yet) lets the engine
+                        # preempt + retry
+                        _reserve_step(self.kv, self._states, live, chunk)
+                if ar_rids:
+                    self._step_ar_paged(ar_rids, infos)
+                if diff_rids:
+                    self._step_diffusion_paged(diff_rids, chunk, infos)
+                with span("serving.backend.decode.trim"):
+                    if live:
+                        _trim_step(self.kv, self._states, live)
+                for r in rids:                # still-prefilling: idle info
+                    if r not in infos:
+                        infos[r] = StepInfo(0, np.zeros(1, bool), 0, False)
+                for r in fresh:
+                    # surface the prefill-derived AR first token in this
+                    # tick's StepInfo so the engine stamps TTFT at the tick
+                    # the last chunk completed (valid_len stays untouched:
+                    # prefill commits don't feed the TU estimator)
+                    fi = infos.get(r)
+                    if fi is None:
+                        infos[r] = StepInfo(1, np.ones(1, bool), 0,
+                                            self._states[r].done)
+                    else:
+                        infos[r] = StepInfo(fi.n_committed + 1,
+                                            fi.commit_mask, fi.valid_len,
+                                            fi.done)
             return 0.0, infos
 
         # recurrent-slot families (ssm AR, hybrid block-pinned diffusion)

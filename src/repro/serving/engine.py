@@ -29,7 +29,7 @@ import numpy as np
 from repro.serving.clock import VirtualClock
 from repro.serving.kv_pool import OutOfPages
 from repro.serving.request import Request, RequestMetrics
-from repro.serving.telemetry import NULL_TRACER
+from repro.serving.telemetry import NULL_TRACER, span, tick_span
 
 
 @dataclass
@@ -229,13 +229,15 @@ class EngineCore:
         self._steps += 1
         if self._steps > self.max_steps:
             raise RuntimeError("engine exceeded max_steps")
-        now = self.clock.now()
-        self._admit(now)
-        if not self._active:
-            if self._pending:
-                self.clock.advance_to(self._earliest_arrival())
-            return True
-        self._decode_once()
+        with tick_span(self._steps):
+            self._admit(self.clock.now())
+            idle = not self._active
+            if not idle:
+                self._decode_once()
+        if idle and self._pending:
+            # nothing to run: jump (or, on a wall clock, wait) to the next
+            # arrival outside the tick's span
+            self.clock.advance_to(self._earliest_arrival())
         return True
 
     def drain(self):
@@ -264,6 +266,13 @@ class EngineCore:
         return free_after >= len(self._active)
 
     def _admit(self, now: float):
+        with span("serving.engine.admit") as sp:
+            before = len(self._active)
+            self._admit_ready(now)
+            sp.set_metadata(admitted=len(self._active) - before)
+
+    def _admit_ready(self, now: float):
+        """Admit arrived requests while the batch and the pool allow."""
         while len(self._active) < self.max_batch:
             i = self._next_admittable(now)
             if i < 0 or not self.backend.can_admit(self._pending[i]) \
@@ -366,6 +375,44 @@ class EngineCore:
         return fn() if fn is not None else 0
 
     def _decode_once(self):
+        with span("serving.scheduler.select") as sp:
+            b, chunk = self._select()
+            sp.set_metadata(b=b, chunk=chunk)
+        with span("serving.engine.capacity") as sp:
+            before = self.preemptions
+            self._ensure_step_capacity(chunk)
+            sp.set_metadata(preempted=self.preemptions - before)
+        t0 = self.clock.now()
+        while True:
+            rids = [r.rid for r in self._active]
+            try:
+                latency, infos = self.backend.decode_step(rids, chunk)
+                break
+            except OutOfPages:
+                # decode_step reserves before mutating, so the step never
+                # partially ran — preempt a victim and retry it
+                if not self._preempt_for_memory():
+                    raise
+        latency *= self._slow_mult()
+        # the step's clock time: what passed while it ran (a wall clock)
+        # plus the latency the backend reports (a virtual clock; zero from
+        # a backend whose work takes real time)
+        dur = self.clock.now() - t0 + latency
+        b = len(self._active)
+        self.clock.advance(latency)
+        self._busy += dur
+        now = self.clock.now()
+        if self._first_decode_t is None:
+            self._first_decode_t = now - dur
+        self._chunk_hist.append((now, b, chunk))
+        self._batch_hist.append(b)
+        with span("serving.engine.commit") as sp:
+            commits, finished = self._commit(infos, now)
+            self.tracer.tick(self, now - dur, dur, b, chunk, commits)
+            sp.set_metadata(committed=commits, finished=finished)
+
+    def _select(self) -> tuple[int, int]:
+        """(decode batch, chunk) of this tick: the scheduler's choice."""
         # b = the batch the decode dispatch will actually run: mid-prefill
         # requests are active but sit chunked-mode dispatches out — their
         # load reaches the scheduler through prefill_tokens, not b (double-
@@ -388,27 +435,11 @@ class EngineCore:
                         b, kv_util=self._kv_utilization())
                 except TypeError:   # ... or the memory signal
                     chunk = self.scheduler.select(b)
-        self._ensure_step_capacity(chunk)
-        while True:
-            rids = [r.rid for r in self._active]
-            try:
-                latency, infos = self.backend.decode_step(rids, chunk)
-                break
-            except OutOfPages:
-                # decode_step reserves before mutating, so the step never
-                # partially ran — preempt a victim and retry it
-                if not self._preempt_for_memory():
-                    raise
-        latency *= self._slow_mult()
-        b = len(self._active)
-        self.clock.advance(latency)
-        self._busy += latency
-        now = self.clock.now()
-        if self._first_decode_t is None:
-            self._first_decode_t = now - latency
-        self._chunk_hist.append((now, b, chunk))
-        self._batch_hist.append(b)
+        return b, chunk
 
+    def _commit(self, infos, now: float) -> tuple[int, int]:
+        """Stamp, retire and feed back one step's commits; returns (tokens
+        committed, requests finished)."""
         commit_masks, valids = [], []
         still_active = []
         commits = 0
@@ -447,9 +478,10 @@ class EngineCore:
                                 preemptions=m.preemptions)
             else:
                 still_active.append(req)
+        finished = len(self._active) - len(still_active)
         self._active = still_active
         self.scheduler.observe(commit_masks, valids)
-        self.tracer.tick(self, now - latency, latency, b, chunk, commits)
+        return commits, finished
 
     # -- fault injection / failover support --------------------------------
     def _slow_mult(self) -> float:

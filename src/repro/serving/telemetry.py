@@ -34,6 +34,16 @@ Event kinds
             and pages freed.
 ``route`` / ``spill`` / ``reject`` — cluster-tier placement decisions.
 
+Profiler spans
+--------------
+:func:`span` and :class:`tick_span` put the serving path's phases into the
+JAX profiler's trace, on the same clock as the device's operations, so a
+trace says which host phase the device waited on.  Every name starts with
+``serving.``; every span opened inside an engine tick carries that tick's
+step number as ``tick``.  Outside a profiler session a span records
+nothing and costs well under a microsecond, so the serving path opens them
+unconditionally, as it calls the tracer.
+
 Exporters: :meth:`Tracer.to_jsonl` (one JSON object per line; the analyzer
 CLI ``python -m repro.launch.trace_view`` consumes this) and
 :meth:`Tracer.to_perfetto` (Chrome ``trace_event`` JSON loadable in
@@ -47,20 +57,53 @@ and — for sharded page pools — per-device
 ``device_dispatches`` / ``collective_bytes`` plus one
 ``pages_in_use/shard<i>`` track per KV shard).
 :func:`validate_trace_events` is an in-repo catapult-format checker used
-by CI's trace smoke job.
+by the tests and ``trace_view --validate-perfetto``.
 """
 
 from __future__ import annotations
 
+import contextvars
 import json
 from collections import deque
+
+from jax.profiler import TraceAnnotation
+
+# step number of the engine tick that encloses the current span (-1: none)
+_TICK = contextvars.ContextVar("serving_tick", default=-1)
+
+
+def span(name: str, **stats):
+    """A profiler span ``name`` (``serving.<layer>.<phase>``) with scalar
+    ``stats`` (counts and ids) and the enclosing tick's step number.  Add
+    stats known only at the end with ``set_metadata`` on the entered
+    span."""
+    return TraceAnnotation(name, tick=_TICK.get(), **stats)
+
+
+class tick_span:
+    """The span of one engine iteration, ``serving.engine.tick``: every
+    :func:`span` opened inside it carries ``tick=step``."""
+
+    __slots__ = ("_step", "_token", "_ann")
+
+    def __init__(self, step: int):
+        self._step = step
+
+    def __enter__(self):
+        self._token = _TICK.set(self._step)
+        self._ann = TraceAnnotation("serving.engine.tick", tick=self._step)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        _TICK.reset(self._token)
 
 
 class NullTracer:
     """No-op tracer: the default wired into every engine.  Every method is
     an empty body so the untraced hot path costs one attribute lookup and
-    one no-op call per instrumentation point (measured in
-    ``benchmarks/telemetry_overhead.py``)."""
+    one no-op call per instrumentation point."""
 
     enabled = False
 
@@ -146,6 +189,7 @@ class Tracer:
             self._append(("req", "prefill_chunk", prid, t0, replica,
                           {"offset": off, "n_tokens": n}))
         self._append(("tick", replica, t0, dur, {
+            "tick": getattr(core, "_steps", -1),
             "b": b, "chunk": chunk, "commits": commits,
             "max_itl": getattr(core, "_max_itl", 0.0),
             "decision": decision, "counters": counters, "gauges": gauges}))

@@ -219,6 +219,28 @@ def test_fused_step_hlo_aliases_page_pool(model_and_params):
     assert check_pool_donation(txt, target="prefill_paged") == []
 
 
+def test_serving_programs_are_named(model_and_params):
+    """The paged programs are jitted under their model methods' names, so
+    HLO dumps and profiler traces name them (not ``jit__unknown``)."""
+    model, params = model_and_params
+    be = ModelBackend(model, params, max_len=64, attn_impl="ref")
+    B, W = 2, be._table_width
+    z = jnp.zeros(B, jnp.int32)
+    tables = jnp.zeros((B, W), jnp.int32)
+    lowered = {
+        "decode_step_paged": be._decode_paged.lower(
+            params, be._pages_cache(), jnp.zeros((B, 4), jnp.int32), z, z,
+            tables, z, z),
+        "prefill_chunk_paged": be._prefill_chunk.lower(
+            params, be._pages_cache(), jnp.zeros((B, 8), jnp.int32), z, z,
+            tables),
+        "prefill_paged": be._prefill_paged.lower(
+            params, be._pages_cache(), jnp.zeros((B, 8), jnp.int32), z,
+            tables)}
+    for name, low in lowered.items():
+        assert low.as_text().startswith(f"module @jit_{name} "), name
+
+
 def test_no_use_after_donate_on_retained_pages_reference(model_and_params):
     """A stale handle to the pre-step pool must raise (buffer deleted), and
     the backend itself must never hold one: after every decode step the

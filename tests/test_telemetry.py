@@ -2,16 +2,20 @@
 scheduler-decision replay (ISSUE acceptance), lifecycle spans, Perfetto
 export validity, ring-buffer bounds, and the preemption starvation guard."""
 
+import time
+
+import jax
 import numpy as np
 import pytest
 
 from repro.cluster import KVAdmissionPolicy, build_sim_cluster
 from repro.core import ElasticScheduler, FixedScheduler
 from repro.core.latency_model import A100_80G
-from repro.models import ArchConfig
-from repro.serving import (DATASETS, EngineCore, NULL_TRACER, PoissonWorkload,
-                           Request, SimBackend, Tracer, load_jsonl,
-                           replay_select, validate_trace_events)
+from repro.models import ArchConfig, build_model
+from repro.serving import (DATASETS, EngineCore, ModelBackend, NULL_TRACER,
+                           PoissonWorkload, Request, SimBackend, Tracer,
+                           WallClock, load_jsonl, replay_select,
+                           validate_trace_events)
 from repro.serving.telemetry import (COUNTER_FIELDS, build_spans,
                                      decision_summary, phase_attribution,
                                      ttft_breakdown)
@@ -111,6 +115,67 @@ def test_tick_events_carry_decision_and_match_history():
             == rec["gauges"]["n_pages"]
         assert rec["counters"]["decode_dispatches"] >= 0
         assert "host_transfer_bytes" in rec["counters"]
+
+
+def test_tick_durations_on_a_virtual_clock_are_the_backend_latency():
+    """On the virtual clock a tick lasts exactly (bit for bit) the latency
+    the backend reported, and the engine's busy time adds them up with
+    the prefill latencies of admission."""
+    be = _backend(seed=7)
+    step_lats, admit_lats = [], []
+    step, admit = be.decode_step, be.admit
+
+    def decode_step(rids, chunk):
+        lat, infos = step(rids, chunk)
+        step_lats.append(lat)
+        return lat, infos
+
+    def admit_rec(req):
+        admit_lats.append(admit(req))
+        return admit_lats[-1]
+    be.decode_step, be.admit = decode_step, admit_rec
+    tr = Tracer()
+    core = EngineCore(be, _scheduler(be), max_batch=64, tracer=tr)
+    core.submit_all(list(PoissonWorkload(PROF, rate=8.0, n_requests=12,
+                                         seed=7)))
+    core.drain()
+    ticks = [r for r in tr.records() if r["kind"] == "tick"]
+    assert [r["dur"] for r in ticks] == step_lats and sum(step_lats) > 0
+    assert core.report().busy_time == pytest.approx(sum(step_lats)
+                                                    + sum(admit_lats))
+    steps = [r["tick"] for r in ticks]
+    assert steps == sorted(set(steps))
+    assert 1 <= steps[0] and steps[-1] <= core._steps
+
+
+def test_tick_durations_on_a_wall_clock_are_the_step_time():
+    """With a real model on the wall clock the backend reports no latency:
+    a tick lasts the wall time its decode step took, and the ticks add up
+    to the time the engine spent in its decode iterations."""
+    cfg = ArchConfig(name="t", family="dense", n_layers=2, d_model=64,
+                     n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
+                     block_size=8)
+    model = build_model(cfg)
+    be = ModelBackend(model, model.init(jax.random.PRNGKey(0)), max_len=64)
+    tr = Tracer()
+    core = EngineCore(be, FixedScheduler(8), max_batch=4, clock=WallClock(),
+                      tracer=tr)
+    spent = []
+    decode_once = core._decode_once
+
+    def timed():
+        t0 = time.perf_counter()
+        decode_once()
+        spent.append(time.perf_counter() - t0)
+    core._decode_once = timed
+    rng = np.random.default_rng(0)
+    core.submit_all([Request(i, 0.0, 12, 16, prompt_tokens=rng.integers(
+        4, cfg.vocab_size, 12).tolist()) for i in range(4)])
+    core.drain()
+    durs = [r["dur"] for r in tr.records() if r["kind"] == "tick"]
+    assert len(durs) == len(spent) and all(d > 0 for d in durs)
+    assert sum(durs) == pytest.approx(sum(spent), rel=0.10)
+    assert core.report().busy_time == pytest.approx(sum(durs))
 
 
 def test_fixed_scheduler_decisions_logged():

@@ -83,6 +83,16 @@ def test_paged_kernel_compiles_for_decode(one_chip, c):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_paged_kernel_keeps_its_name(one_chip):
+    """The compiled custom call carries the kernel's ``name``, so a chip
+    trace names the kernel's op after it."""
+    compiled = _compile_kernel(one_chip, B=8, c=32, W=16, n_pages=256,
+                               dtype=jnp.bfloat16)
+    op = next(line for line in compiled.as_text().splitlines()
+              if 'custom_call_target="tpu_custom_call"' in line)
+    assert op.lstrip().startswith("%chunked_paged_attn")
+
+
 def test_paged_kernel_compiles_for_largest_prefill_chunk(one_chip):
     """4·8192 query rows per KV head: the row-tile grid axis keeps the
     kernel's VMEM bounded whatever the chunk length."""
