@@ -34,6 +34,7 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute")
 
 _SHAPE_RE = re.compile(r"\b([a-z0-9]+)\[([0-9,]*)\]")
+_LAYOUT = re.compile(r"\{[^{}]*\}")
 _TRIP = re.compile(r'"known_trip_count":\s*\{\s*"n"\s*:\s*"?(\d+)"?')
 _CALL_ATTR = re.compile(
     r"(?:body|condition|calls|to_apply|branch_computations|"
@@ -123,8 +124,10 @@ def parse_hlo(text: str):
             continue
         lhs, _, rhs = s.partition(" = ")
         name = lhs.replace("ROOT", "").strip().lstrip("%")
-        # rhs starts with result type(s) then "opcode("
-        mo = re.search(r"\b([\w\-]+)\(", rhs)
+        # rhs starts with result type(s) then "opcode("; blank the layouts
+        # first, whose TPU tiling ("{...:T(8,128)(2,1)}") reads as a call
+        mo = re.search(r"\b([\w\-]+)\(", _LAYOUT.sub(
+            lambda m: " " * len(m.group()), rhs))
         if not mo:
             continue
         opcode = mo.group(1)

@@ -180,46 +180,52 @@ def _local_slots(tables, ctx_lens, stripe_offs, shard, *, n_shards,
 
 
 def split_kv_paged_partial(q, k_pages, v_pages, block_tables, ctx_lens,
-                           stripe_offs, ks: KVShardSpec, *,
+                           stripe_offs, ks: KVShardSpec, layer=None, *,
                            interpret: bool, impl: str = "kernel",
                            scale: float | None = None):
     """Split-KV chunked paged attention across ``ks.axis``.
 
-    q [B,c,H,D] replicated; k/v_pages [P,ps,KVH,D] page-dim-sharded;
-    block_tables [B,W] GLOBAL page ids (replicated, strict striping per
+    q [B,c,H,D] replicated; k/v_pages the stacked [L,P,ps,KVH,D] pool read
+    at ``layer`` (int32 scalar, may be traced) or one layer's [P,ps,KVH,D]
+    with ``layer`` None, page-dim-sharded either way; block_tables [B,W]
+    GLOBAL page ids (replicated, strict striping per
     :class:`KVShardSpec`); ctx_lens/stripe_offs [B].  Each shard runs
     ``paged_chunk_attention_kernel`` (or the jnp oracle) over its local
-    pages only, then the flash partials merge exactly across shards
-    (``merge_flash_partials`` pmax/psum).  Returns the *merged partial*
-    ``(acc [B,c,H,D] fp32, m [B,c,H], l [B,c,H])`` replicated — the same
-    contract as the unsharded kernel, so the caller combines it with the
-    in-window partial unchanged.
+    pages of the layer, read in place, then the flash partials merge exactly
+    across shards (``merge_flash_partials`` pmax/psum).  Returns the
+    *merged partial* ``(acc [B,c,H,D] fp32, m [B,c,H], l [B,c,H])``
+    replicated — the same contract as the unsharded kernel, so the caller
+    combines it with the in-window partial unchanged.
     """
-    P_g, ps = k_pages.shape[0], k_pages.shape[1]
+    from repro.kernels.ops import merge_flash_partials
+    if k_pages.ndim == 4:
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    P_g, ps = k_pages.shape[1], k_pages.shape[2]
     P_loc = P_g // ks.n_shards
+    if impl == "ref":
+        from repro.kernels.ref import paged_chunk_ref as attend
+    else:
+        from repro.kernels.chunked_paged_attn import \
+            paged_chunk_attention_kernel
+        attend = functools.partial(paged_chunk_attention_kernel,
+                                   interpret=interpret)
 
-    def body(q_, kp, vp, tables, ctx, offs):
+    def body(q_, kp, vp, tables, ctx, offs, lyr):
         shard = jax.lax.axis_index(ks.axis)
         lt, lctx = _local_slots(tables, ctx, offs, shard,
                                 n_shards=ks.n_shards, pages_local=P_loc,
                                 page_size=ps)
-        if impl == "ref":
-            from repro.kernels import ref
-            part = ref.paged_chunk_ref(q_, kp, vp, lt, lctx, scale=scale)
-        else:
-            from repro.kernels.chunked_paged_attn import \
-                paged_chunk_attention_kernel
-            part = paged_chunk_attention_kernel(
-                q_, kp, vp, lt, lctx, scale=scale, interpret=interpret)
-        from repro.kernels.ops import merge_flash_partials
+        part = attend(q_, kp, vp, lt, lctx, lyr, scale=scale)
         return merge_flash_partials([part], axis_name=ks.axis)
 
+    pool = P(None, ks.axis)
     fn = jax.shard_map(
         body, mesh=ks.mesh,
-        in_specs=(P(), P(ks.axis), P(ks.axis), P(), P(), P()),
+        in_specs=(P(), pool, pool, P(), P(), P(), P()),
         out_specs=(P(), P(), P()), check_vma=False)
     return fn(q, k_pages, v_pages, block_tables.astype(jnp.int32),
-              ctx_lens.astype(jnp.int32), stripe_offs.astype(jnp.int32))
+              ctx_lens.astype(jnp.int32), stripe_offs.astype(jnp.int32),
+              jnp.asarray(layer, jnp.int32))
 
 
 def scatter_pages_sharded(pages, new, dest, ks: KVShardSpec):

@@ -5,6 +5,11 @@ paged KV cache: Q [B, c, H, D] vs pages [P, page_size, KVH, D] indirected
 through per-request block tables.  This is the TPU-native adaptation of the
 paper's Triton paged-attention kernel:
 
+* the pool is read in place as the model stacks it, [L, P, page_size, KVH,
+  D], with the layer as the first scalar-prefetch operand: the scanned layer
+  stack hands the kernel the whole pool and its own layer index, so no
+  per-layer [P, page_size, KVH, D] slice is ever materialised (a 4-D pool is
+  one layer, reshaped for free);
 * the grid is (batch, row_tile, page_slot); page indirection happens in the
   BlockSpec ``index_map`` via scalar-prefetched block tables (the TPU
   equivalent of the warp-level gather on GPU), so each step DMAs one whole
@@ -46,10 +51,10 @@ NEG_INF = -1e30
 ROW_TILE = 256
 
 
-def _kernel(tables_ref, lens_ref,           # scalar prefetch (SMEM)
-            q_ref, k_ref, v_ref,            # VMEM tiles
-            o_ref, m_ref, l_ref,            # outputs
-            acc_sc, m_sc, l_sc,             # VMEM scratch
+def _kernel(layer_ref, tables_ref, lens_ref,   # scalar prefetch (SMEM)
+            q_ref, k_ref, v_ref,                # VMEM tiles
+            o_ref, m_ref, l_ref,                # outputs
+            acc_sc, m_sc, l_sc,                 # VMEM scratch
             *, page_size: int, n_slots: int, n_kv_heads: int, scale: float):
     b = pl.program_id(0)
     i = pl.program_id(2)
@@ -96,17 +101,23 @@ def _kernel(tables_ref, lens_ref,           # scalar prefetch (SMEM)
 
 
 def paged_chunk_attention_kernel(q, k_pages, v_pages, block_tables, ctx_lens,
-                                 *, scale: float | None = None,
+                                 layer=None, *, scale: float | None = None,
                                  interpret: bool = False):
     """Raw kernel invocation.
 
-    q [B, c, H, D]; k_pages/v_pages [P, page_size, KVH, D];
-    block_tables [B, n_slots] int32 (entries must be valid page indices —
-    pad with 0); ctx_lens [B] int32.
+    q [B, c, H, D]; k_pages/v_pages [L, P, page_size, KVH, D] with ``layer``
+    an int32 scalar (may be traced) picking the layer read, or one layer's
+    [P, page_size, KVH, D] with ``layer`` None; block_tables [B, n_slots]
+    int32 (entries must be valid page indices — pad with 0); ctx_lens [B]
+    int32.
     Returns flash partials: acc [B,c,H,D] fp32, m/l [B,c,H].
     """
+    if k_pages.ndim == 4:
+        assert layer is None, "a 4-D pool is one layer"
+        k_pages, v_pages, layer = k_pages[None], v_pages[None], 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     B, c, H, D = q.shape
-    P, page_size, KVH, _ = k_pages.shape
+    page_size, KVH = k_pages.shape[2:4]
     G = H // KVH
     R = G * c
     n_slots = block_tables.shape[1]
@@ -126,24 +137,24 @@ def paged_chunk_attention_kernel(q, k_pages, v_pages, block_tables, ctx_lens,
                                n_slots=n_slots, n_kv_heads=KVH, scale=scale)
     grid = (B, Rp // rt, n_slots)
 
-    def page_map(b, r, i, t, ln):
+    def page_map(b, r, i, lyr, t, ln):
         # clamp to the row's last live slot: an unchanged block index
         # between steps skips the page fetch
         last = jnp.maximum((ln[b] + page_size - 1) // page_size - 1, 0)
-        return (t[b, jnp.minimum(i, last)], 0, 0, 0)
+        return (lyr[0], t[b, jnp.minimum(i, last)], 0, 0, 0)
 
-    def row_map(b, r, i, t, ln):
+    def row_map(b, r, i, lyr, t, ln):
         return (b, 0, r, 0)
 
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((None, KVH, rt, D), row_map),
-                pl.BlockSpec((None, page_size, KVH, D), page_map),
-                pl.BlockSpec((None, page_size, KVH, D), page_map),
+                pl.BlockSpec((None, None, page_size, KVH, D), page_map),
+                pl.BlockSpec((None, None, page_size, KVH, D), page_map),
             ],
             out_specs=[
                 pl.BlockSpec((None, KVH, rt, D), row_map),
@@ -165,7 +176,7 @@ def paged_chunk_attention_kernel(q, k_pages, v_pages, block_tables, ctx_lens,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="chunked_paged_attn",
-    )(block_tables, ctx_lens, qg, k_pages, v_pages)
+    )(layer, block_tables, ctx_lens, qg, k_pages, v_pages)
 
     # ungroup: [B, KVH, G, c, D] → [B, c, H, D] partials
     acc = acc[:, :, :R].reshape(B, KVH, G, c, D).transpose(0, 3, 1, 2, 4) \

@@ -9,13 +9,17 @@ import numpy as np
 NEG_INF = -1e30
 
 
-def paged_chunk_ref(q, k_pages, v_pages, block_tables, ctx_lens, *,
-                    scale: float | None = None):
+def paged_chunk_ref(q, k_pages, v_pages, block_tables, ctx_lens, layer=None,
+                    *, scale: float | None = None):
     """Oracle for chunked paged attention partials.
 
     Gathers pages into a contiguous [B, S, KVH, D] cache and computes masked
     flash partials (acc fp32, m, l) with shapes matching the kernel output.
+    A stacked [L, P, ps, KVH, D] pool is read at ``layer``, as the kernel
+    reads it.
     """
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
     B, c, H, D = q.shape
     P, ps, KVH, _ = k_pages.shape
     G = H // KVH

@@ -302,32 +302,32 @@ class TransformerLM:
                 parts.append(flash_partial(
                     q, kc, vc, q_pos=pos1d, k_pos=k_pos,
                     k_valid=k_pos < shared["cache_len"][:, None], kind="all"))
-            if "page_k" in lx:
+            if "pool_layer" in lx:
                 # paged prefix: block-table-indirected flash partial over the
                 # page pool (Pallas chunked-paged-attention kernel, or the
-                # pure-jnp oracle when paged_attn_impl == "ref").  With a
+                # pure-jnp oracle when paged_attn_impl == "ref").  The pool
+                # is the whole stacked [L, P, ps, KVH, hd] array, read in
+                # place at this layer's index: neither sliced per layer nor
+                # converted (the kernel casts each page it fetches).  With a
                 # sharded pool the partial is computed split-KV over the kv
                 # mesh axis — each shard attends over its local pages only
                 # and the partials merge exactly (pmax/psum) on device.
-                kp = lx["page_k"].astype(cfg.cdt)
-                vp = lx["page_v"].astype(cfg.cdt)
+                args = (q, shared["k_pages"], shared["v_pages"],
+                        shared["block_tables"], shared["ctx_lens"])
                 ks = shared.get("kv_shard")
                 interpret = resolve_interpret(shared["paged_interpret"])
                 if ks is not None:
                     from repro.distributed.collectives import \
                         split_kv_paged_partial
                     parts.append(split_kv_paged_partial(
-                        q, kp, vp, shared["block_tables"],
-                        shared["ctx_lens"], shared["shard_offs"], ks,
+                        *args, shared["shard_offs"], ks, lx["pool_layer"],
                         impl=shared["paged_impl"], interpret=interpret))
                 elif shared["paged_impl"] == "ref":
                     parts.append(kernel_ref.paged_chunk_ref(
-                        q, kp, vp, shared["block_tables"],
-                        shared["ctx_lens"]))
+                        *args, lx["pool_layer"]))
                 else:
                     parts.append(paged_chunk_attention_kernel(
-                        q, kp, vp, shared["block_tables"],
-                        shared["ctx_lens"], interpret=interpret))
+                        *args, lx["pool_layer"], interpret=interpret))
             if "self_flash" in shared:
                 sf = shared["self_flash"]
                 B, T = pos1d.shape
@@ -733,18 +733,13 @@ class TransformerLM:
         """
         from repro.kernels.ops import softmax_confidence_device
         self._check_paged()
-        B, T = tokens.shape
+        T = tokens.shape[1]
         offs = jnp.arange(T, dtype=jnp.int32)
         positions = offsets[:, None] + offs[None, :]
         validm = offs[None, :] < valid[:, None]
         shared = self._window_masks(cache, positions, validm, T)
-        shared.update(block_tables=block_tables.astype(jnp.int32),
-                      ctx_lens=offsets.astype(jnp.int32),
-                      paged_impl=impl, paged_interpret=interpret)
-        self._shared_kv_shard(shared, kv_shard, shard_offs, B)
-        per_layer = {f"pos{j}": {"page_k": cache["k_pages"],
-                                 "page_v": cache["v_pages"]}
-                     for j in self.attn_positions()}
+        per_layer = self._paged_prefix(shared, cache, block_tables, offsets,
+                                       impl, interpret, kv_shard, shard_offs)
         x = self.embed(params, tokens, mm_embeds, mm_mask)
         x, kvs, _ = self._stack(params, x, positions, shared, per_layer)
         idx = jnp.clip(valid - 1, 0, T - 1)
@@ -762,16 +757,29 @@ class TransformerLM:
         conf, tok = softmax_confidence_device(logits)
         return conf, tok, new_cache
 
-    @staticmethod
-    def _shared_kv_shard(shared, kv_shard, shard_offs, B):
-        """Install the sharded-pool fields read by ``_mixer_apply``'s
-        paged branch (no-op when the pool is unsharded)."""
-        if kv_shard is None:
-            return
-        if shard_offs is None:
-            shard_offs = jnp.zeros((B,), jnp.int32)
-        shared.update(kv_shard=kv_shard,
-                      shard_offs=shard_offs.astype(jnp.int32))
+    def _paged_prefix(self, shared, cache, block_tables, ctx_lens, impl,
+                      interpret, kv_shard, shard_offs):
+        """Install the paged-prefix fields read by ``_mixer_apply``'s paged
+        branch and return the per-layer xs that carry each layer's index.
+
+        The whole stacked pool goes in ``shared``: the scan closes over it
+        (a loop-invariant operand, never sliced), and each layer hands the
+        kernel its index into the pool's layer dim."""
+        attn = self.attn_positions()
+        # the pool stacks attention layers position-major (_collect_kv):
+        # with one attention position per period its layer is the scan index
+        assert len(attn) == 1, "paged pool needs one attention layer/period"
+        shared.update(k_pages=cache["k_pages"], v_pages=cache["v_pages"],
+                      block_tables=block_tables.astype(jnp.int32),
+                      ctx_lens=ctx_lens.astype(jnp.int32),
+                      paged_impl=impl, paged_interpret=interpret)
+        if kv_shard is not None:
+            if shard_offs is None:
+                shard_offs = jnp.zeros(ctx_lens.shape, jnp.int32)
+            shared.update(kv_shard=kv_shard,
+                          shard_offs=shard_offs.astype(jnp.int32))
+        return {f"pos{attn[0]}": {
+            "pool_layer": jnp.arange(self.n_periods, dtype=jnp.int32)}}
 
     def chunk_forward_paged(self, params, cache, win_tokens, win_start,
                             win_valid, block_tables, ctx_lens, *,
@@ -788,18 +796,13 @@ class TransformerLM:
         empty and the in-window diagonal keeps logits finite).
         """
         self._check_paged()
-        B, c = win_tokens.shape
+        c = win_tokens.shape[1]
         offs = jnp.arange(c, dtype=jnp.int32)
         positions = win_start[:, None] + offs[None, :]
         valid = offs[None, :] < win_valid[:, None]
         shared = self._window_masks(cache, positions, valid, c)
-        shared.update(block_tables=block_tables.astype(jnp.int32),
-                      ctx_lens=ctx_lens.astype(jnp.int32),
-                      paged_impl=impl, paged_interpret=interpret)
-        self._shared_kv_shard(shared, kv_shard, shard_offs, B)
-        per_layer = {f"pos{j}": {"page_k": cache["k_pages"],
-                                 "page_v": cache["v_pages"]}
-                     for j in self.attn_positions()}
+        per_layer = self._paged_prefix(shared, cache, block_tables, ctx_lens,
+                                       impl, interpret, kv_shard, shard_offs)
         x = self.embed(params, win_tokens, mm_embeds, mm_mask)
         x, kvs, _ = self._stack(params, x, positions, shared, per_layer)
         logits = self.head(params, x)

@@ -948,8 +948,9 @@ class PagedKVAllocator:
                      kv_axis: str = "kv"):
         """Allocate the device page pool: [L_attn, P, page_size, KVH, hd].
 
-        Each scanned attention layer reads its own [P, page_size, KVH, hd]
-        slice — exactly the layout ``paged_chunk_attention_kernel`` expects.
+        The stacked layout is what ``paged_chunk_attention_kernel`` reads in
+        place: each scanned attention layer passes the whole pool and its
+        layer index, so no layer's [P, page_size, KVH, hd] slice is copied.
 
         With ``mesh`` the pool is laid out sharded on the page dim: the
         PartitionSpec comes from ``rules`` (``kv_shard_rules`` — logical

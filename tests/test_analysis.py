@@ -22,7 +22,8 @@ import pytest
 from repro.analysis import fixtures, lint, rules
 from repro.analysis.findings import (Finding, apply_allowlist,
                                      parse_allowlist)
-from repro.analysis.hlo import entry_result_shapes, nonaliased_output_bytes
+from repro.analysis.hlo import (entry_result_shapes, nonaliased_output_bytes,
+                                parse_hlo)
 from repro.analysis.jaxpr import byte_traffic
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -234,6 +235,26 @@ def test_entry_result_shapes_parses_header():
                                         ("s32", (8,), 32)]
     acct = nonaliased_output_bytes(txt)
     assert acct["total"] == 64 and acct["fresh"] == 64
+
+
+def test_parse_hlo_reads_opcodes_past_tpu_tiled_layouts():
+    """A TPU layout's tiling (``T(8,128)(2,1)``, ``S(1)``) precedes the
+    opcode and is no call: the opcode, operands and called computations
+    are read after it."""
+    txt = ("HloModule jit_f\n\n"
+           "%fused (a: bf16[8,128]) -> bf16[8,128] {\n"
+           "  %a = bf16[8,128]{1,0:T(8,128)(2,1)} parameter(0)\n"
+           "  ROOT %n = bf16[8,128]{1,0:T(8,128)(2,1)} negate(%a)\n}\n\n"
+           "ENTRY %main (p: bf16[8,128]) -> bf16[8,128] {\n"
+           "  %p = bf16[8,128]{1,0:T(8,128)(2,1)} parameter(0)\n"
+           "  %c = bf16[8,128]{1,0:T(8,128)(2,1)S(1)} copy(%p)\n"
+           "  ROOT %f = bf16[8,128]{1,0:T(8,128)(2,1)} fusion(%c), "
+           "kind=kLoop, calls=%fused\n}\n")
+    comps, fusion_bodies, entry = parse_hlo(txt)
+    assert [(i.opcode, i.operands) for i in comps[entry].instrs] == [
+        ("parameter", []), ("copy", ["p"]), ("fusion", ["c"])]
+    assert fusion_bodies == {"fused"}
+    assert comps[entry].instrs[1].result[0][2] == [8, 128]
 
 
 # ---------------------------------------------------------------------------

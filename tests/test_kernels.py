@@ -50,6 +50,78 @@ def test_paged_chunk_attention(shape, dtype):
     np.testing.assert_allclose(l, l_r, rtol=_tol(dtype), atol=1e-5)
 
 
+@pytest.mark.parametrize("c", [1, 80])       # 80·4 rows: two row tiles
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_paged_kernel_reads_stacked_pool_at_layer(layer, c):
+    """The kernel reads a stacked [L, P, ps, KVH, D] pool in place at a
+    traced layer index, exactly as the oracle reads that layer's slice.
+    The layers hold different values, so a wrong layer index fails.  Row 0
+    has no context, row 1's table is padded past its context, rows 1 and 2
+    share a page."""
+    from repro.kernels.chunked_paged_attn import paged_chunk_attention_kernel
+    L, H, KVH, D, ps, P = 3, 8, 2, 64, 16, 12
+    rng = np.random.default_rng(layer)
+    q = jnp.asarray(rng.normal(size=(3, c, H, D)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(L, P, ps, KVH, D)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(L, P, ps, KVH, D)), jnp.float32)
+    tables = jnp.asarray([[0, 0, 0, 0],
+                          [5, 7, 0, 0],
+                          [9, 7, 2, 11]], jnp.int32)
+    lens = jnp.asarray([0, 20, 64], jnp.int32)
+    acc, m, l = paged_chunk_attention_kernel(q, kp, vp, tables, lens,
+                                             jnp.int32(layer),
+                                             interpret=True)
+    acc_r, m_r, l_r = ref.paged_chunk_ref(q, kp[layer], vp[layer], tables,
+                                          lens)
+    np.testing.assert_allclose(m, m_r, rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(l, l_r, rtol=2e-5, atol=1e-5)
+    np.testing.assert_allclose(acc, acc_r, rtol=2e-5, atol=2e-5)
+    assert not np.allclose(acc, ref.paged_chunk_ref(
+        q, kp[(layer + 1) % L], vp[(layer + 1) % L], tables, lens)[0])
+
+
+def test_paged_decode_step_kernel_matches_ref_per_layer():
+    """A fused paged decode step of a 3-layer model whose pool layers hold
+    different values: the kernel path, reading the stacked pool by layer
+    index, agrees with the oracle path; rolling the pool's layers changes
+    the result, so a wrong layer index cannot pass."""
+    import jax
+
+    from repro.models.common import ArchConfig
+    from repro.models.registry import build_model
+    cfg = ArchConfig(name="t", family="dense", n_layers=3, d_model=64,
+                     n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=256,
+                     block_size=8)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    L, KVH, hd = model.paged_kv_dims()
+    P, ps, B, c = 16, 8, 3, 8
+    rng = np.random.default_rng(7)
+    pool = {n: jnp.asarray(rng.normal(size=(L, P, ps, KVH, hd)), jnp.float32)
+            for n in ("k_pages", "v_pages")}
+    win = jnp.asarray(rng.integers(4, cfg.vocab_size, (B, c)), jnp.int32)
+    ctx = jnp.asarray([0, 13, 30], jnp.int32)
+    tables = jnp.asarray([[0, 0, 0, 0, 0, 0],
+                          [3, 8, 4, 0, 0, 0],
+                          [9, 8, 1, 12, 6, 0]], jnp.int32)
+    args = (win, ctx, jnp.full((B,), c, jnp.int32), tables, ctx,
+            jnp.asarray([1, 2, 3], jnp.int32))
+
+    def step(impl, cache):
+        return model.decode_step_paged(params, cache, *args, impl=impl,
+                                       interpret=True)
+
+    conf_k, tok_k, cache_k = step("kernel", pool)
+    conf_r, tok_r, cache_r = step("ref", pool)
+    np.testing.assert_allclose(conf_k, conf_r, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(tok_k, tok_r)
+    for n in pool:
+        np.testing.assert_allclose(cache_k[n], cache_r[n], rtol=2e-5,
+                                   atol=2e-5)
+    rolled = {n: jnp.roll(a, 1, axis=0) for n, a in pool.items()}
+    assert not np.allclose(step("ref", rolled)[0], conf_r)
+
+
 def test_paged_combined_matches_contiguous():
     """Full chunk attention (paged partial + window part) must equal plain
     attention over [cache ‖ window]."""
