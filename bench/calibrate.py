@@ -7,7 +7,8 @@ With random weights the logits are nearly flat, no position ever crosses
 the confidence threshold, and every diffusion step commits one token per
 request.  The gain of the final norm scales the logits.  This serves
 ShareGPT-shaped requests with the program's fixed-BD32 scheduler at
-several gains, in one process, and prints the committed tokens per step
+several gains, in one process (the model by the configuration's model
+module, with its pool and serving options), and prints the committed tokens per step
 per request at each, then the gain whose value is nearest the target
 (5.29, ShareGPT under BD32 in the paper's Table 2).  Gains are multiples
 of 1/8, exact in bfloat16.
@@ -65,23 +66,18 @@ def main():
         H.configure_cache()
     import jax
 
-    from repro.launch.serve import init_model, model_serving
-    from repro.models.common import ArchConfig
-    from repro.serving import DATASETS
     from repro.serving.request import Request
     if not args.smoke and jax.devices()[0].platform != "tpu":
         sys.exit("calibrate: needs a TPU")
     if args.smoke:
         TRAFFIC["prompt"]["max"], TRAFFIC["output"]["max"] = 48, 24
     cfg = S.load_json(ROOT / args.config)
-    spec = S.model_spec(cfg, smoke=args.smoke)
-    model, params = init_model(ArchConfig(**S.arch_kwargs(spec)),
-                               H.weight_seed(args.seed))
+    model = S.model_module(cfg)
+    spec = S.model_spec(cfg, smoke=args.smoke, model=model)
     span = Request(-1, 0.0, TRAFFIC["prompt"]["max"],
                    TRAFFIC["output"]["max"], prompt_tokens=[])
-    backend, sched = model_serving(model, params, [span], "bd32",
-                                   DATASETS["sharegpt"], seed=args.seed,
-                                   attn_impl="kernel")
+    _, backend, sched = H.serve(cfg, model, spec, H.weight_seed(args.seed),
+                                [span], "bd32", "sharegpt", args.seed)
     seen = {}
 
     def at(g):

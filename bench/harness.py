@@ -40,6 +40,8 @@ NO_GAP = 1e30
 # a CPU rehearsal (tests only) runs the cell's mix at these sizes
 REHEARSAL = {"prompt_max": 40, "output_max": 20, "rate": 1.5,
              "lead_in_s": 1.0, "drain_s": 60.0, "max_batch": 4}
+# the keys of a configuration's "serving" object: ModelBackend options
+SERVING_OPTIONS = ("prefill_token_budget",)
 # programs the traced run names spans after: (owner, method, span)
 SPANS = (("core", "_admit", "bench.engine.admit"),
          ("core", "_decode_once", "bench.engine.tick"),
@@ -161,20 +163,62 @@ def attn_work(spec: dict, rec: Tick, peak: dict):
     return ops, nbytes, bound
 
 
-def tick_flops(spec: dict, rec: Tick) -> float:
-    total = F.prefill_flops(spec, [(o, n) for _, o, n in rec.plan])
+def tick_flops(model, spec: dict, rec: Tick) -> float:
+    """Model FLOPs of one tick's dispatches, by ``model``'s (a model
+    module's) count."""
+    total = model.prefill_flops(spec, [(o, n) for _, o, n in rec.plan])
     if rec.dec is not None:
-        total += F.decode_flops(spec, rec.dec[2], rec.dec[3])
+        total += model.decode_flops(spec, rec.dec[2], rec.dec[3])
     return total
 
 
 def serving_kw(config: dict) -> dict:
     """The program's serving options a configuration sets: the page pool
-    in tokens (default: the HBM left after the weights)."""
+    in tokens (``kv_pool_tokens``; default: the HBM left after the
+    weights), and its ``serving`` object, whose keys are
+    ``SERVING_OPTIONS``, passed to ``model_serving`` as they are."""
     kw = {}
     if config.get("kv_pool_tokens"):
         kw["kv_pages"] = config["kv_pool_tokens"] // config["kv_page_size"]
+    opts = config.get("serving", {})
+    unknown = sorted(set(opts) - set(SERVING_OPTIONS))
+    if unknown:
+        raise ValueError(f"unknown serving option(s) {unknown} in "
+                         f"configuration {config.get('name')!r}; allowed: "
+                         + ", ".join(SERVING_OPTIONS))
+    kw.update(opts)
     return kw
+
+
+def serve(config: dict, model, spec: dict, wseed: int, requests,
+          mode: str, dataset: str, seed: int):
+    """(program model, backend, scheduler): the configuration's model by
+    its model module ``model``, with weights from ``wseed`` and the spec's
+    logit gain as the final norm's scale, served by the program's
+    ``model_serving`` with the configuration's serving options and the
+    ``mode`` scheduler."""
+    import jax
+
+    from repro.launch.serve import init_model, model_serving
+    from repro.models.common import ArchConfig
+    from repro.serving import DATASETS
+    lm, params = init_model(ArchConfig(**model.arch_kwargs(spec)), wseed)
+    params["final_norm"]["scale"] = jax.numpy.full_like(
+        params["final_norm"]["scale"], spec["logit_gain"])
+    backend, sched = model_serving(lm, params, requests, mode,
+                                   DATASETS[dataset], seed=seed,
+                                   attn_impl="kernel", **serving_kw(config))
+    return lm, backend, sched
+
+
+def rehearsal_traffic(traffic: dict) -> dict:
+    """A cell's mix at the sizes a CPU rehearsal runs (tests only)."""
+    return dict(traffic,
+                prompt=dict(traffic["prompt"], max=REHEARSAL["prompt_max"]),
+                output=dict(traffic["output"], max=REHEARSAL["output_max"]),
+                arrivals=dict(traffic["arrivals"], rate=REHEARSAL["rate"]),
+                lead_in_s=REHEARSAL["lead_in_s"],
+                drain_s=REHEARSAL["drain_s"])
 
 
 def pow2s(limit: int):
@@ -214,6 +258,23 @@ def prefill_shapes(budget: int, align: int, max_batch: int,
     return out
 
 
+def prefill_budget(backend) -> int:
+    """The most prompt tokens a tick's prefill plan can hand out: the
+    configured budget, or the adaptive one's target."""
+    pf = backend._prefill
+    return max(pf.target_bc or pf.budget, pf.align)
+
+
+def warm_runs(backend, max_batch: int, candidates, traffic: dict) -> list:
+    """Every dispatch shape the mix can reach: ("decode", rows, chunk) and
+    ("prefill", rows, tokens), by the backend's prefill budget."""
+    runs = [("decode", B, c) for B in pow2s(max_batch) for c in candidates]
+    runs += [("prefill", B, n) for B, n in prefill_shapes(
+        prefill_budget(backend), backend._prefill.align, max_batch,
+        traffic["prompt"]["min"], traffic["prompt"]["max"])]
+    return runs
+
+
 def warm(backend, spec: dict, max_batch: int, candidates, traffic: dict,
          log=print):
     """Run every decode and prefill shape the mix can reach once, with
@@ -224,12 +285,7 @@ def warm(backend, spec: dict, max_batch: int, candidates, traffic: dict,
     i32 = jnp.int32
     W = backend._table_width
     mask = spec["mask_token_id"]
-    pf = backend._prefill
-    budget = max(pf.target_bc or pf.budget, pf.align)
-    runs = [("decode", B, c) for B in pow2s(max_batch) for c in candidates]
-    runs += [("prefill", B, n) for B, n in prefill_shapes(
-        budget, pf.align, max_batch, traffic["prompt"]["min"],
-        traffic["prompt"]["max"])]
+    runs = warm_runs(backend, max_batch, candidates, traffic)
     refused = []
     for kind, B, n in runs:
         z = jnp.asarray(np.zeros(B, np.int64), i32)
@@ -254,7 +310,7 @@ def warm(backend, spec: dict, max_batch: int, candidates, traffic: dict,
         np.asarray(conf)
     log(f"warm-up: {len(runs) - len(refused)} shapes of {len(runs)} "
         f"(decode rows x chunk, prefill rows x tokens; prefill budget "
-        f"{budget} tokens); refused by the compiler for memory: "
+        f"{prefill_budget(backend)} tokens); refused by the compiler for memory: "
         f"{refused or 'none'}")
     return refused
 
@@ -287,6 +343,7 @@ class RunData:
     sampled: list           # RequestMetrics of requests due in the window
     ticks: list             # [Tick] whose step ended in the window
     trace: object | None    # bench.trace.TraceSummary
+    model: object = None    # the configuration's model module
 
 
 def build_sample(rec: Recorder, arrivals: dict, sampled_rids, seed):
@@ -347,9 +404,10 @@ def uncompared(sample) -> int:
     return missing
 
 
-def check(spec, wseed, sample, max_ctx, limits, control=False):
-    """Replay the sample on the reference; the numbers ``correct``
-    compares, each with its limit, and what was counted on the way.
+def check(model, spec, wseed, sample, max_ctx, limits, control=False):
+    """Replay the sample on the reference of ``model`` (a model module);
+    the numbers ``correct`` compares, each with its limit, and what was
+    counted on the way.
 
     With ``control`` the reference computed in fp8 takes the program's
     place: its choice of positions and tokens in every window of the
@@ -359,10 +417,11 @@ def check(spec, wseed, sample, max_ctx, limits, control=False):
     thr = spec["confidence_threshold"]
     decisions = alt = None
     if control and sample:
-        ctl = R.replay(spec, wseed, sample, max_ctx, quant="fp8")
+        ctl = R.replay(model, spec, wseed, sample, max_ctx, quant="fp8")
         decisions = R.control_decisions(sample, ctl, thr)
         alt = {k: out for k, (_, out) in decisions.items()}
-    ref = R.replay(spec, wseed, sample, max_ctx, alt=alt) if sample else {}
+    ref = R.replay(model, spec, wseed, sample, max_ctx, alt=alt) \
+        if sample else {}
     missing = uncompared(sample)
 
     def numbers(j):
@@ -399,35 +458,22 @@ def run_cell(cell: S.Cell, seed: int, seconds: float, trace: bool, *,
     (see :func:`check`)."""
     import jax
 
-    from repro.launch.serve import init_model, model_serving
-    from repro.models.common import ArchConfig
-    from repro.serving import DATASETS
     from repro.serving.engine import EngineCore
     from repro.serving.request import Request
 
     devs = jax.devices()
     dev = devs[0]
     compiles = CompileCounter().install()
-    spec = S.model_spec(cell.config, smoke=rehearse)
+    spec = S.model_spec(cell.config, smoke=rehearse, model=cell.model)
     traffic = dict(cell.traffic)
     max_batch = cell.config["max_batch"]
     if rehearse:
-        traffic.update(prompt=dict(traffic["prompt"],
-                                   max=REHEARSAL["prompt_max"]),
-                       output=dict(traffic["output"],
-                                   max=REHEARSAL["output_max"]),
-                       arrivals=dict(traffic["arrivals"],
-                                     rate=REHEARSAL["rate"]),
-                       lead_in_s=REHEARSAL["lead_in_s"],
-                       drain_s=REHEARSAL["drain_s"])
+        traffic = rehearsal_traffic(traffic)
         max_batch = REHEARSAL["max_batch"]
     peak = None if rehearse else F.peak(dev.device_kind)
     wseed = weight_seed(seed)
 
     # -- set-up ---------------------------------------------------------
-    model, params = init_model(ArchConfig(**S.arch_kwargs(spec)), wseed)
-    params["final_norm"]["scale"] = jax.numpy.full_like(
-        params["final_norm"]["scale"], spec["logit_gain"])
     arrivals = T.generate(traffic, seed, seconds, spec["vocab_size"])
     requests = [Request(a.rid, a.due, a.prompt_len, a.output_len,
                         prompt_tokens=a.tokens.tolist(),
@@ -435,11 +481,9 @@ def run_cell(cell: S.Cell, seed: int, seconds: float, trace: bool, *,
     max_ctx = T.longest_context(traffic)
     span = Request(-1, 0.0, traffic["prompt"]["max"],
                    traffic["output"]["max"], prompt_tokens=[])
-    backend, sched = model_serving(model, params, requests + [span],
-                                   "elastic", DATASETS[traffic["dataset"]],
-                                   seed=seed, attn_impl="kernel",
-                                   **serving_kw(cell.config))
-    del params
+    lm, backend, sched = serve(cell.config, cell.model, spec, wseed,
+                               requests + [span], "elastic",
+                               traffic["dataset"], seed)
     if not rehearse:
         warm(backend, spec, max_batch, sched.candidates, traffic, log=log)
     if on_backend is not None:
@@ -560,7 +604,7 @@ def run_cell(cell: S.Cell, seed: int, seconds: float, trace: bool, *,
                 f"{len(summary.modules)} program runs: "
                 + ", ".join(sorted({m.name for m in summary.modules})))
         shutil.rmtree(tdir, ignore_errors=True)
-    data = RunData(spec, peak, sm, win_ticks, summary)
+    data = RunData(spec, peak, sm, win_ticks, summary, cell.model)
     layer = {}
     if trace:
         for m in cell.per_layer:
@@ -575,11 +619,11 @@ def run_cell(cell: S.Cell, seed: int, seconds: float, trace: bool, *,
                           seed + 1)
     free(backend.params)
     free(backend._pages_cache())
-    del backend, core, sched, model, rec
+    del backend, core, sched, lm, rec
     gc.collect()
     t_ref = time.perf_counter()
     limits = (cell.config["smoke"] if rehearse else cell.config)["limits"]
-    checks, info = check(spec, wseed, sample, max_ctx, limits,
+    checks, info = check(cell.model, spec, wseed, sample, max_ctx, limits,
                          control=control)
     log(f"check: {info['requests']} requests ({info['served']} served "
         f"tokens), {info['windows']} windows, {info['compared']} served "

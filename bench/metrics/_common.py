@@ -54,7 +54,7 @@ def step_mfu(run):
     if run.peak is None or not run.ticks:
         return None
     wall = sum(t.t1 - t.t0 for t in run.ticks)
-    work = sum(tick_flops(run.spec, t) for t in run.ticks)
+    work = sum(tick_flops(run.model, run.spec, t) for t in run.ticks)
     return 100.0 * work / (wall * run.peak["flops"]) if wall and work \
         else None
 

@@ -1,14 +1,14 @@
 """Plain reference of the served model, and the replay that decides
 ``correct``.
 
-A decoder-only transformer with the published layer equations (RMSNorm,
-rotate-half RoPE, grouped-query attention, SwiGLU MLP, LM head), in
+The model's own equations live in its model module
+(``bench/models/<name>.py``: weights from the seed, the prompt pass, one
+window against its cached prefix); this file holds what every model
+shares: the layer pieces those modules build from (RMSNorm, rotate-half
+RoPE, grouped-query attention, the fp8 rounding of the control), the
+replay that drives a module over the sample, and the judgement.  All in
 float32 with the highest matmul precision, no kernel, no page pool, no
-batching logic.  It imports nothing of the program: it makes its own
-weights from the seed, by the same draws as the repository's random
-initialisation (every matrix N(0, 1/fan_in), the embedding N(0, 0.02²),
-norms 1, from one threefry key chain, in the order below), and applies the
-configuration's logit gain as the final norm's scale.
+batching logic, importing nothing of the program.
 
 Block diffusion with streaming chunks caches the keys and values of a
 position when it was an input of a window, so a served token can only be
@@ -67,79 +67,29 @@ class RequestTrace:
     windows: list = field(default_factory=list)
 
 
-def _jax():
+def jax_modules():
     import jax
     import jax.numpy as jnp
     return jax, jnp
 
 
-def _split(key):
-    jax, _ = _jax()
+def split(key):
+    jax, _ = jax_modules()
     key, sub = jax.random.split(key)
     return key, sub
 
 
-def _dense(key, shape, dtype, fan_in):
-    jax, jnp = _jax()
+def dense_init(key, shape, dtype, fan_in):
+    jax, jnp = jax_modules()
     return (jax.random.normal(key, shape, jnp.float32)
             * (1.0 / np.sqrt(fan_in))).astype(dtype)
 
 
-def init_weights(spec: dict, seed: int):
-    """Weights from ``seed`` (one jit, on the default device)."""
-    jax, jnp = _jax()
-    d, H, KVH, D, F, V, L = (spec["hidden_size"], spec["num_attention_heads"],
-                             spec["num_key_value_heads"], spec["head_dim"],
-                             spec["intermediate_size"], spec["vocab_size"],
-                             spec["num_hidden_layers"])
-    dt = jnp.dtype(spec["dtype"])
-
-    def stacked(key, draw):
-        return jax.lax.scan(lambda k, _: draw(k), key, None, length=L)
-
-    def norm(key):
-        key, _ = _split(key)
-        return key, ()
-
-    def attn(key):
-        out = []
-        for shape, fan in (((d, H * D), d), ((d, KVH * D), d),
-                           ((d, KVH * D), d), ((H * D, d), H * D)):
-            key, sub = _split(key)
-            out.append(_dense(sub, shape, dt, fan))
-        return key, tuple(out)
-
-    def mlp(key):
-        out = []
-        for shape, fan in (((d, F), d), ((F, d), F), ((d, F), d)):
-            key, sub = _split(key)
-            out.append(_dense(sub, shape, dt, fan))
-        return key, tuple(out)
-
-    def draw(key):
-        key, _ = stacked(key, norm)
-        key, (wq, wk, wv, wo) = stacked(key, attn)
-        key, _ = stacked(key, norm)
-        key, (up, down, gate) = stacked(key, mlp)
-        key, sub = _split(key)
-        embed = (jax.random.normal(sub, (V, d), jnp.float32)
-                 * 0.02).astype(dt)
-        key, _ = _split(key)                      # the final norm's draw
-        w = dict(wq=wq, wk=wk, wv=wv, wo=wo, up=up, down=down, gate=gate,
-                 embed=embed)
-        if not spec["tie_word_embeddings"]:
-            key, sub = _split(key)
-            w["head"] = _dense(sub, (d, V), dt, d)
-        return w
-
-    return jax.jit(draw)(jax.random.PRNGKey(seed))
-
-
 # -- forward ---------------------------------------------------------------
 
-def _mat(w, quant):
+def mat(w, quant):
     """A weight matrix [in, out] as float32, fp8-rounded for the control."""
-    _, jnp = _jax()
+    _, jnp = jax_modules()
     w = w.astype(jnp.float32)
     if quant is None:
         return w
@@ -148,21 +98,21 @@ def _mat(w, quant):
     return (w / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) * scale
 
 
-def _embed(w, tokens, quant):
-    _, jnp = _jax()
-    table = _mat(w["embed"].T, quant).T if quant else \
+def embed(w, tokens, quant):
+    _, jnp = jax_modules()
+    table = mat(w["embed"].T, quant).T if quant else \
         w["embed"].astype(jnp.float32)
     return table[tokens]
 
 
-def _rms(x, eps, scale=1.0):
-    jax, jnp = _jax()
+def rms(x, eps, scale=1.0):
+    jax, jnp = jax_modules()
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """Rotate-half RoPE: x [..., T, h, D], pos [..., T]."""
-    _, jnp = _jax()
+    _, jnp = jax_modules()
     D = x.shape[-1]
     inv = (1.0 / theta ** (np.arange(0, D, 2) / D)).astype(np.float32)
     ang = pos[..., None].astype(jnp.float32) * inv
@@ -171,26 +121,26 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def _dot(a, b):
-    jax, jnp = _jax()
+def dot(a, b):
+    jax, jnp = jax_modules()
     return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
-def _qkv(spec, lw, x, pos, quant):
+def qkv(spec, lw, x, pos, quant):
     H, KVH, D = (spec["num_attention_heads"], spec["num_key_value_heads"],
                  spec["head_dim"])
-    h = _rms(x, spec["rms_norm_eps"])
-    q = _dot(h, _mat(lw["wq"], quant)).reshape(*x.shape[:-1], H, D)
-    k = _dot(h, _mat(lw["wk"], quant)).reshape(*x.shape[:-1], KVH, D)
-    v = _dot(h, _mat(lw["wv"], quant)).reshape(*x.shape[:-1], KVH, D)
+    h = rms(x, spec["rms_norm_eps"])
+    q = dot(h, mat(lw["wq"], quant)).reshape(*x.shape[:-1], H, D)
+    k = dot(h, mat(lw["wk"], quant)).reshape(*x.shape[:-1], KVH, D)
+    v = dot(h, mat(lw["wv"], quant)).reshape(*x.shape[:-1], KVH, D)
     th = spec["rope_theta"]
-    return _rope(q, pos, th), _rope(k, pos, th), v
+    return rope(q, pos, th), rope(k, pos, th), v
 
 
-def _attend(q, k, v, mask):
+def attend(q, k, v, mask):
     """q [..., T, H, D], k/v [..., S, KVH, D], mask [..., T, S] → [..., T,
     H·D]: softmax over every allowed key, grouped-query."""
-    jax, jnp = _jax()
+    jax, jnp = jax_modules()
     *lead, T, H, D = q.shape
     KVH = k.shape[-2]
     qg = q.reshape(*lead, T, KVH, H // KVH, D)
@@ -203,80 +153,14 @@ def _attend(q, k, v, mask):
     return o.reshape(*lead, T, H * D)
 
 
-def _mlp(spec, lw, x, quant):
-    jax, _ = _jax()
-    h = _rms(x, spec["rms_norm_eps"])
-    g = jax.nn.silu(_dot(h, _mat(lw["gate"], quant)))
-    return _dot(g * _dot(h, _mat(lw["up"], quant)), _mat(lw["down"], quant))
-
-
-def _layers(w):
-    return {k: w[k] for k in ("wq", "wk", "wv", "wo", "up", "down", "gate")}
-
-
-def prompt_kv(spec, w, tokens, length, quant=None):
-    """Keys and values of a prompt [T] (padded; ``length`` live tokens)
-    under block-causal attention: ([L, T, KVH, D], same)."""
-    jax, jnp = _jax()
-    T = tokens.shape[0]
-    bs = spec["block_size"]
-    pos = jnp.arange(T)
-    x = _embed(w, tokens, quant)
-    qc = min(QUERY_CHUNK, T)
-
-    def layer(x, lw):
-        q, k, v = _qkv(spec, lw, x, pos, quant)
-
-        def block(i):
-            qp = i * qc + jnp.arange(qc)
-            qi = jax.lax.dynamic_slice_in_dim(q, i * qc, qc)
-            mask = (pos[None, :] // bs <= qp[:, None] // bs) \
-                & (pos[None, :] < length)
-            return _attend(qi, k, v, mask)
-
-        o = jax.lax.map(block, jnp.arange(T // qc)).reshape(T, -1)
-        x = x + _dot(o, _mat(lw["wo"], quant))
-        return x + _mlp(spec, lw, x, quant), (k, v)
-
-    _, (ks, vs) = jax.lax.scan(layer, x, _layers(w))
-    return ks, vs
-
-
-def window_step(spec, w, ck, cv, win, start, valid, tok_a, tok_b,
-                quant=None):
-    """One window per row against its cached prefix.
-
-    ck/cv [L, n, S, KVH, D] (positions < start are the prefix), win [n, W]
-    tokens, start/valid [n].  Returns (best logit [n, W], argmax [n, W],
-    log-odds [n, W], logit of tok_a [n, W], logit of tok_b [n, W], ck, cv)
-    with the window's keys and values written at ``start``; the log-odds
-    are log p/(1 − p) of the best token's softmax probability p, taken
-    without forming p so that they stay finite where p rounds to 1."""
-    jax, jnp = _jax()
-    n, W = win.shape
-    S = ck.shape[2]
-    bs = spec["block_size"]
-    pos = start[:, None] + jnp.arange(W)[None]
-    ok = jnp.arange(W)[None] < valid[:, None]
-    self_mask = (pos[:, None, :] // bs <= pos[:, :, None] // bs) \
-        & ok[:, None, :] & ok[:, :, None] | jnp.eye(W, dtype=bool)[None]
-    pre_mask = jnp.broadcast_to(
-        (jnp.arange(S)[None, :] < start[:, None])[:, None, :], (n, W, S))
-    x = _embed(w, win, quant)
-
-    def layer(x, inp):
-        lw, pk, pv = inp
-        q, k, v = _qkv(spec, lw, x, pos, quant)
-        o = _attend(q, jnp.concatenate([pk, k], 1),
-                    jnp.concatenate([pv, v], 1),
-                    jnp.concatenate([pre_mask, self_mask], 2))
-        x = x + _dot(o, _mat(lw["wo"], quant))
-        return x + _mlp(spec, lw, x, quant), (k, v)
-
-    x, (ks, vs) = jax.lax.scan(layer, x, (_layers(w), ck, cv))
-    h = _rms(x, spec["rms_norm_eps"], spec["logit_gain"])
-    head = w["embed"].T if spec["tie_word_embeddings"] else w["head"]
-    logits = _dot(h, _mat(head, quant))
+def read_logits(logits, start, ck, cv, ks, vs, tok_a, tok_b):
+    """What :func:`replay` reads of a window's logits [n, W, V]: (best
+    logit, argmax, log-odds, logit of tok_a, logit of tok_b, ck, cv), with
+    the window's keys and values ks/vs [L, n, W, ...] written into the
+    caches ck/cv [L, n, S, ...] at ``start``.  The log-odds are log p/(1 −
+    p) of the best token's softmax probability p, taken without forming p
+    so that they stay finite where p rounds to 1."""
+    jax, jnp = jax_modules()
 
     def put(c, new):            # c [L, n, S, ...], new [L, n, W, ...]
         return jax.vmap(lambda cr, nr, s: jax.lax.dynamic_update_slice_in_dim(
@@ -301,25 +185,27 @@ def _pad_len(n: int) -> int:
     return p if p <= QUERY_CHUNK else -(-n // QUERY_CHUNK) * QUERY_CHUNK
 
 
-def replay(spec: dict, seed: int, sample: list, max_ctx: int,
+def replay(model, spec: dict, seed: int, sample: list, max_ctx: int,
            quant=None, alt: dict | None = None, slots: int = 4):
-    """Run the reference over ``sample`` ([RequestTrace]), ``slots``
-    requests at a time in lock step.
+    """Run the reference of ``model`` (a model module) over ``sample``
+    ([RequestTrace]), ``slots`` requests at a time in lock step.
 
     Returns {(rid, window index): {"best", "arg", "odds", "out", "alt"}},
     each an array over the window's positions: the best logit, its token,
     its log-odds, and the logits of the window's ``out`` tokens
     and of ``alt[(rid, window index)]`` (another choice of tokens)."""
     import functools
-    jax, jnp = _jax()
-    w = init_weights(spec, seed)
+    jax, jnp = jax_modules()
+    w = model.init_weights(spec, seed)
     L, KVH, D = (spec["num_hidden_layers"], spec["num_key_value_heads"],
                  spec["head_dim"])
     S = max_ctx + WINDOW
     mask_id = spec["mask_token_id"]
     alt = alt or {}
-    kv_fn = jax.jit(functools.partial(prompt_kv, spec, quant=quant))
-    step_fn = jax.jit(functools.partial(window_step, spec, quant=quant),
+    kv_fn = jax.jit(functools.partial(model.prompt_kv, spec,
+                                      quant=quant))
+    step_fn = jax.jit(functools.partial(model.window_step, spec,
+                                        quant=quant),
                       donate_argnums=(1, 2))
     out = {}
     with jax.default_matmul_precision("highest"):

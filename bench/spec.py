@@ -4,12 +4,16 @@ Everything that belongs to one configuration, one traffic mix or one
 per-layer metric sits in a file of its own and is found by its name:
 
 * a configuration: the ``file`` its ``configs`` entry gives (JSON);
+* its model: ``bench/models/<model>.py``, where the configuration names
+  ``"model": "<model>"`` (``dense`` where it names none): the keys its
+  spec carries, the program's ``ArchConfig`` arguments, the reference's
+  model-specific parts and the model FLOPs (see ``bench/models/dense.py``);
 * a traffic mix: ``bench/traffic/<traffic>.json``;
 * a per-layer metric: ``bench/metrics/<name>.py``, a module with
   ``read(run) -> float | None``.
 
-So a new cell, configuration or metric is new files and entries, never an
-edit of the harness.
+So a new cell, configuration, model or metric is new files and entries,
+never an edit of the harness.
 """
 
 from __future__ import annotations
@@ -21,6 +25,7 @@ from pathlib import Path
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+DEFAULT_MODEL = "dense"
 
 
 def load_benchmark(root: Path = ROOT) -> dict:
@@ -36,6 +41,7 @@ class Cell:
     name: str
     chips: int
     config: dict            # the configuration file's contents
+    model: object           # the configuration's model module
     traffic: dict           # the traffic file's contents
     end_to_end: list        # BENCHMARK.json entries this cell reports
     per_layer: list
@@ -59,21 +65,32 @@ def resolve_cell(name: str, bench: dict | None = None,
     e2e = [m for m in bench["end_to_end"] if _applies(m, name)]
     names = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"] if _applies(m, name, names)]
-    return Cell(name=name, chips=int(w["chips"]),
-                config=load_json(root / conf["file"]),
+    config = load_json(root / conf["file"])
+    return Cell(name=name, chips=int(w["chips"]), config=config,
+                model=model_module(config, bench_dir),
                 traffic=load_json(bench_dir / "traffic"
                                   / f"{w['traffic']}.json"),
                 end_to_end=e2e, per_layer=per_layer)
 
 
-def metric_reader(name: str, bench_dir: Path = BENCH_DIR):
-    """The ``read`` function of ``bench/metrics/<name>.py``."""
-    path = bench_dir / "metrics" / f"{name}.py"
+def _load(kind: str, name: str, bench_dir: Path):
+    """The module ``bench/<kind>/<name>.py``."""
+    path = bench_dir / kind / f"{name}.py"
     spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+        f"bench_{kind}_{name.replace('.', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, bench_dir: Path = BENCH_DIR):
+    """The ``read`` function of ``bench/metrics/<name>.py``."""
+    return _load("metrics", name, bench_dir).read
+
+
+def model_module(config: dict, bench_dir: Path = BENCH_DIR):
+    """The model module a configuration names."""
+    return _load("models", config.get("model", DEFAULT_MODEL), bench_dir)
 
 
 def list_cells(bench: dict | None = None) -> list[str]:
@@ -81,38 +98,32 @@ def list_cells(bench: dict | None = None) -> list[str]:
     return [w["name"] for w in bench["workloads"]]
 
 
-# -- configuration file → the program's ArchConfig ---------------------------
+# -- configuration file → the numbers the model is run with -------------------
 
-# published (Hugging Face config.json) key → ArchConfig field
-_HF_KEYS = {
+# published (Hugging Face config.json) key → ArchConfig field, for the keys
+# every configuration has
+HF_KEYS = {
     "num_hidden_layers": "n_layers", "hidden_size": "d_model",
     "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
     "head_dim": "head_dim", "intermediate_size": "d_ff",
     "vocab_size": "vocab_size", "rope_theta": "rope_theta",
     "rms_norm_eps": "norm_eps", "tie_word_embeddings": "tie_embeddings",
 }
-_SERVING_KEYS = ("block_size", "confidence_threshold", "mask_token_id",
-                 "kv_page_size")
+SERVING_KEYS = ("block_size", "confidence_threshold", "mask_token_id",
+                "kv_page_size")
 
 
-def model_spec(config: dict, smoke: bool = False) -> dict:
+def model_spec(config: dict, smoke: bool = False, model=None) -> dict:
     """The numbers the model is run with: the published keys, the serving
-    keys and the logit gain, with the file's ``smoke`` overrides applied
-    for a CPU rehearsal."""
-    spec = {k: config[k] for k in (*_HF_KEYS, *_SERVING_KEYS)}
+    keys, the keys its model module adds (``model``, by default the one the
+    configuration names) and the logit gain, with the file's ``smoke``
+    overrides applied for a CPU rehearsal."""
+    model = model_module(config) if model is None else model
+    spec = {k: config[k] for k in (*HF_KEYS, *SERVING_KEYS,
+                                   *model.SPEC_KEYS)}
     spec["dtype"] = config["torch_dtype"]
     spec["hidden_act"] = config["hidden_act"]
     spec["logit_gain"] = float(config["logit_gain"])
     if smoke:
         spec.update(config["smoke"])
     return spec
-
-
-def arch_kwargs(spec: dict) -> dict:
-    """``ArchConfig`` keyword arguments for ``spec``."""
-    kw = {field: spec[k] for k, field in _HF_KEYS.items()}
-    kw.update({k: spec[k] for k in _SERVING_KEYS})
-    kw.update(name="bench", family="dense", act=spec["hidden_act"],
-              param_dtype=spec["dtype"], compute_dtype=spec["dtype"],
-              paged_attn_impl="kernel", remat=False)
-    return kw

@@ -6,12 +6,13 @@ for admission, or admitted with its prompt not yet prefilled.
 
     python3 bench/sweep.py --workload <cell> --rates 2 4 8 16 --seconds 20
 
-One process and one set-up (the cell's configuration, pool and warm-up);
-each rate then gets a fresh engine and scheduler on the same backend and
+One process and one set-up (the cell's model by its model module, its
+pool and serving options, and the warm-up); each rate then gets a fresh engine and scheduler on the same backend and
 runs its lead-in and window open loop, after which every request still in
 flight is released.  Prints one line per rate and a JSON summary whose
 ``knee`` is the highest sustained rate; a cell file takes a fixed share of
-it as its ``arrivals.rate``.
+it as its ``arrivals.rate``.  ``--smoke`` rehearses the same on the CPU
+at the configuration's smoke size (no warm-up, no TPU needed).
 """
 
 import sys
@@ -41,36 +42,35 @@ def main():
     ap.add_argument("--rates", type=float, nargs="+", required=True)
     ap.add_argument("--seconds", type=float, default=20.0)
     ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--smoke", action="store_true",
+                    help="CPU rehearsal at the configuration's smoke size")
     args = ap.parse_args()
     from bench import harness as H
     from bench import spec as S
     from bench import stats
     from bench import traffic as T
-    H.configure_cache()
+    if not args.smoke:
+        H.configure_cache()
     import jax
 
-    from repro.launch.serve import init_model, model_serving
-    from repro.models.common import ArchConfig
-    from repro.serving import DATASETS
     from repro.serving.engine import EngineCore
     from repro.serving.request import Request
-    if jax.devices()[0].platform != "tpu":
+    if not args.smoke and jax.devices()[0].platform != "tpu":
         sys.exit("sweep: needs a TPU")
     cell = S.resolve_cell(args.workload)
-    spec = S.model_spec(cell.config)
+    spec = S.model_spec(cell.config, smoke=args.smoke, model=cell.model)
     traffic = cell.traffic
-    model, params = init_model(ArchConfig(**S.arch_kwargs(spec)),
-                               H.weight_seed(args.seed))
-    params["final_norm"]["scale"] = jax.numpy.full_like(
-        params["final_norm"]["scale"], spec["logit_gain"])
+    max_batch = cell.config["max_batch"]
+    if args.smoke:
+        traffic = H.rehearsal_traffic(traffic)
+        max_batch = H.REHEARSAL["max_batch"]
     span = Request(-1, 0.0, traffic["prompt"]["max"],
                    traffic["output"]["max"], prompt_tokens=[])
-    backend, sched0 = model_serving(model, params, [span], "elastic",
-                                    DATASETS[traffic["dataset"]],
-                                    seed=args.seed, attn_impl="kernel",
-                                    **H.serving_kw(cell.config))
-    max_batch = cell.config["max_batch"]
-    H.warm(backend, spec, max_batch, sched0.candidates, traffic)
+    _, backend, sched0 = H.serve(cell.config, cell.model, spec,
+                                 H.weight_seed(args.seed), [span], "elastic",
+                                 traffic["dataset"], args.seed)
+    if not args.smoke:
+        H.warm(backend, spec, max_batch, sched0.candidates, traffic)
     print(f"set-up {time.perf_counter() - T0:.1f} s", flush=True)
     rows = []
     lo = traffic["lead_in_s"]
@@ -86,13 +86,16 @@ def main():
         core.submit_all(reqs)
         w_lo = tokens = None
         batches = []
-        while clock.now() < hi:
+        while True:
             now = clock.now()
+            # the window opens at the first tick boundary at or after lo
             if w_lo is None and now >= lo:
                 w_lo = waiting(core, now)
                 done0 = sum(m.n_tokens for m in core.report().metrics)
                 act0 = sum(backend.state(r.rid).n_committed
                            for r in core.active_requests())
+            if now >= hi:
+                break
             core.tick()
             if now >= lo:
                 batches.append(core.n_active)

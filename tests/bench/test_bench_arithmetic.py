@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from bench import flops as F
+from bench import spec as S
 from bench import stats
 from bench.harness import prefill_shapes, pow2_ceil
 
@@ -45,15 +46,16 @@ def test_paged_attn_call_by_hand():
 
 
 def test_decode_and_prefill_flops_by_hand():
+    dense = S.model_module({})
     p_layer = 8 * 16 + 2 * 8 * 8 + 16 * 8 + 3 * 8 * 16
-    assert F.layer_matmul_params(SPEC) == p_layer
+    assert dense.layer_matmul_params(SPEC) == p_layer
     # one row: ctx 5, 2 valid positions
     per_tok = 2 * 2 * p_layer + 2 * 8 * 10
     attn = 4 * 2 * 4 * 4 * (2 * (5 + 2))
-    assert F.decode_flops(SPEC, [5], [2]) == per_tok * 2 + attn
+    assert dense.decode_flops(SPEC, [5], [2]) == per_tok * 2 + attn
     # chunk at offset 4, 3 tokens, block 4: positions 4,5,6 see keys < 7
     attn = 4 * 2 * 4 * 4 * (7 + 7 + 7)
-    assert F.prefill_flops(SPEC, [(4, 3)]) == 2 * 2 * p_layer * 3 + attn
+    assert dense.prefill_flops(SPEC, [(4, 3)]) == 2 * 2 * p_layer * 3 + attn
 
 
 def test_unknown_device_kind_is_an_error():

@@ -1,9 +1,11 @@
 """BENCHMARK.json and the files it names keep to the benchmark's
 contract: names, units, bounds, one reader per per-layer metric, every
-configuration, traffic mix and cell present and used."""
+configuration, its model module, serving options, traffic mix and cell
+present and used."""
 
 import re
 
+from bench import harness as H
 from bench import spec as S
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -28,7 +30,12 @@ def test_configs_cells_and_traffic_exist_and_are_used():
         assert cfg["reduced"] == c["reduced"]
         for k in c["reduced"]:
             assert NAME.match(k)
-        S.model_spec(cfg)
+        model = S.model_module(cfg)
+        for attr in ("SPEC_KEYS", "arch_kwargs", "init_weights", "prompt_kv",
+                     "window_step", "decode_flops", "prefill_flops"):
+            assert hasattr(model, attr), (c["name"], attr)
+        S.model_spec(cfg, model=model)
+        H.serving_kw(cfg)
     seen = set()
     for w in B["workloads"]:
         assert NAME.match(w["name"]) and w["chips"] in (1, 4)

@@ -6,12 +6,15 @@ the cell's limits come from ``bench/control.py``."""
 
 import time
 
+import pytest
+
 from bench import harness as H
 from bench import spec as S
 
 
-def test_fp8_control_fails_where_the_program_passes():
-    cell = S.resolve_cell("sdar8b-sharegpt")
+@pytest.mark.parametrize("name", S.list_cells())
+def test_fp8_control_fails_where_the_program_passes(name):
+    cell = S.resolve_cell(name)
     res = H.run_cell(cell, 3, 4.0, False, t_start=time.perf_counter(),
                      rehearse=True, control=True, log=lambda s: None)
     prog = res["program_checks"]

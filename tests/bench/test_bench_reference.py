@@ -9,11 +9,13 @@ import pytest
 from bench import reference as R
 from bench import spec as S
 
+DENSE = S.model_module({})
+
 
 def _program(spec, seed):
     from repro.launch.serve import init_model
     from repro.models.common import ArchConfig
-    return init_model(ArchConfig(**S.arch_kwargs(spec)), seed)
+    return init_model(ArchConfig(**DENSE.arch_kwargs(spec)), seed)
 
 
 def test_reference_weights_equal_the_programs():
@@ -23,7 +25,7 @@ def test_reference_weights_equal_the_programs():
                            smoke=dict(base["smoke"], num_key_value_heads=4))):
         spec = S.model_spec(cfg, smoke=True)
         _, p = _program(spec, 12345)
-        w = R.init_weights(spec, 12345)
+        w = DENSE.init_weights(spec, 12345)
         blk = p["blocks"]["pos0"]
         pairs = [(blk["mixer"][k], w[k]) for k in ("wq", "wk", "wv", "wo")]
         pairs += [(blk["ffn"][k], w[m]) for k, m in
@@ -80,7 +82,7 @@ def test_prompt_keys_match_the_programs_prefill():
                                    jnp.asarray([P]), tables)
     got = np.asarray(pages["k_pages"]).reshape(L, -1, KVH, D)[:, :P]
     with jax.default_matmul_precision("highest"):
-        k, _ = R.prompt_kv(spec, R.init_weights(spec, 5), jnp.asarray(toks),
-                           P)
+        k, _ = DENSE.prompt_kv(spec, DENSE.init_weights(spec, 5),
+                               jnp.asarray(toks), P)
     np.testing.assert_allclose(got, np.asarray(k)[:, :P], rtol=1e-4,
                                atol=1e-4)

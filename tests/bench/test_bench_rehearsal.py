@@ -22,6 +22,10 @@ from bench import harness as H
 from bench import spec as S
 
 CELL = "sdar8b-sharegpt"
+# one cell of each configuration, to rehearse its smoke block
+_B = S.load_benchmark()
+CONFIG_CELLS = [next(w["name"] for w in _B["workloads"]
+                     if w["config"] == c["name"]) for c in _B["configs"]]
 
 
 def _run(args, cwd=S.ROOT):
@@ -50,8 +54,9 @@ def test_refuses_in_a_directory_of_only_the_benchmark(tmp_path):
     assert '"correct"' not in out.stdout
 
 
-def test_rehearsal_prints_correct_false_and_no_device_metric():
-    out = _run(["--workload", CELL, "--seed", str(2**31 + 11), "--seconds",
+@pytest.mark.parametrize("cell", CONFIG_CELLS)
+def test_rehearsal_prints_correct_false_and_no_device_metric(cell):
+    out = _run(["--workload", cell, "--seed", str(2**31 + 11), "--seconds",
                 "3", "--trace", "0", "--rehearse"])
     assert out.returncode == 0, out.stderr[-2000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
@@ -105,8 +110,9 @@ def _prefill_writes_nothing(backend):
 @pytest.mark.parametrize("fault", [None, _alter_tokens, _commit_everything,
                                    _pool_unchanged_by_decode,
                                    _prefill_writes_nothing])
-def test_check_sees_each_fault(fault):
-    cell = S.resolve_cell(CELL)
+@pytest.mark.parametrize("name", S.list_cells())
+def test_check_sees_each_fault(name, fault):
+    cell = S.resolve_cell(name)
     lines = []
     res = H.run_cell(cell, 7, 3.0, False, t_start=time.perf_counter(),
                      rehearse=True, on_backend=fault, log=lines.append)

@@ -45,7 +45,8 @@ def traced(tmp_path_factory):
 
     cell = S.resolve_cell(CELL)
     spec = S.model_spec(cell.config, smoke=True)
-    model, params = init_model(ArchConfig(**S.arch_kwargs(spec)), 5)
+    model, params = init_model(ArchConfig(**cell.model.arch_kwargs(spec)),
+                               5)
     reqs = [Request(i, 0.0, p, o,
                     prompt_tokens=list(range(4 + 50 * i, 4 + 50 * i + p)))
             for i, (p, o) in enumerate(LENGTHS)]
