@@ -344,6 +344,9 @@ class RunData:
     ticks: list             # [Tick] whose step ended in the window
     trace: object | None    # bench.trace.TraceSummary
     model: object = None    # the configuration's model module
+    # bench.spans.SpanSummary: the program's serving.* spans and their
+    # stats (its counters) in the traced window; None on an untraced run
+    spans: object | None = None
 
 
 def build_sample(rec: Recorder, arrivals: dict, sampled_rids, seed):
@@ -594,17 +597,22 @@ def run_cell(cell: S.Cell, seed: int, seconds: float, trace: bool, *,
     log("end-to-end: " + ", ".join(f"{k} {v:.4f}" for k, v in e2e.items()))
 
     # -- per-layer metrics (traced run) ------------------------------------
-    summary = None
+    summary = spans = None
     if traced:
+        from bench.spans import read_spans
+        from bench.trace import load_profile, reduce_trace
+        profile = load_profile(next(tdir.rglob("*.xplane.pb")))
         if not rehearse:
-            from bench.trace import reduce_trace
-            summary = reduce_trace(next(tdir.rglob("*.xplane.pb")))
+            summary = reduce_trace(profile)
             log(f"trace: {summary.window_s:.3f} s window, device busy "
                 f"{summary.busy_s:.3f} s, {len(summary.ops)} ops, "
                 f"{len(summary.modules)} program runs: "
                 + ", ".join(sorted({m.name for m in summary.modules})))
+        spans = read_spans(profile)
+        log(f"spans: {len(spans.spans)} serving spans in the traced "
+            f"window, {len(spans.by_name())} names")
         shutil.rmtree(tdir, ignore_errors=True)
-    data = RunData(spec, peak, sm, win_ticks, summary, cell.model)
+    data = RunData(spec, peak, sm, win_ticks, summary, cell.model, spans)
     layer = {}
     if trace:
         for m in cell.per_layer:

@@ -5,11 +5,15 @@ from __future__ import annotations
 
 from bench import stats
 
-# the decode and prefill programs, by the host span waiting for them; the
-# paged-attention kernel, the only Pallas kernel of both, by its HLO text
+# the decode and prefill programs, by the host span waiting for them
 DECODE_SPAN = "bench.backend.decode_dispatch"
 PREFILL_SPAN = "bench.backend.prefill_tick"
-PAGED_KERNEL = r'custom_call_target="tpu_custom_call"'
+# the paged-attention kernel, by the name of its Pallas call's op
+# (``%chunked_paged_attn.N``), so that another Pallas kernel of the same
+# program is not counted; a program that leaves the call unnamed shows it
+# as ``%closed_call.N``
+PAGED_KERNEL = (r'^%(chunked_paged_attn|closed_call)(\.\d+)? = .*'
+                r'custom_call_target="tpu_custom_call"')
 TICK_MARK = "bench.mark.tick"
 
 

@@ -15,6 +15,23 @@ device's idle time over the innermost serving span open during it: a gap
 that outlasts one phase is cut at the phase's edges, not given whole to
 the phase at its midpoint.  A trace of a program without these spans
 gives an empty summary, whose readings are None.
+
+A traced run of the harness hands this summary to every per-layer reader
+as ``run.spans`` (None on an untraced run).  It is how a reader sees what
+the program counts: a program counter is a numeric stat on a
+``serving.*`` span, and a reader sums it over the window by the span's
+name through :meth:`SpanSummary.by_name`, for example the rows of every
+decode launch::
+
+    def read(run):
+        if run.spans is None:
+            return None
+        launch = run.spans.by_name().get("serving.backend.decode.launch")
+        return launch["rows"] if launch else None
+
+So a model whose layers count something (routed tokens, experts hit)
+opens a span with that stat in the program and adds one reader file in
+``bench/metrics/``; the harness is not edited.
 """
 
 from __future__ import annotations
@@ -23,7 +40,7 @@ from collections import defaultdict
 from dataclasses import dataclass, field
 
 from bench.trace import (DEVICE_PLANE, OPS_LINE, OUTSIDE, WINDOW_SPAN,
-                         _union)
+                         _union, load_profile)
 
 PREFIX = "serving."
 TICK = "serving.engine.tick"
@@ -100,9 +117,7 @@ class SpanSummary:
 def read_spans(trace) -> SpanSummary:
     """Summarize the serving spans of ``trace`` (a path to an
     ``.xplane.pb``, or its ``jax.profiler.ProfileData``)."""
-    import jax
-    pd = trace if hasattr(trace, "planes") else \
-        jax.profiler.ProfileData.from_file(str(trace))
+    pd = load_profile(trace)
     spans, window, ops = [], None, None
     for plane in pd.planes:
         if DEVICE_PLANE.match(plane.name):

@@ -10,10 +10,14 @@ per-layer metric sits in a file of its own and is found by its name:
   model-specific parts and the model FLOPs (see ``bench/models/dense.py``);
 * a traffic mix: ``bench/traffic/<traffic>.json``;
 * a per-layer metric: ``bench/metrics/<name>.py``, a module with
-  ``read(run) -> float | None``.
+  ``read(run) -> float | None``; ``run`` is ``bench.harness.RunData``.
 
-So a new cell, configuration, model or metric is new files and entries,
-never an edit of the harness.
+A reader finds the program's own counts on ``run.spans`` (a traced run's
+``bench.spans.SpanSummary``, None on an untraced run): a program counter
+is a numeric stat on a ``serving.*`` span, summed over the traced window
+by span name through ``run.spans.by_name()``.  So a new cell,
+configuration, model or metric, a metric of what a new model counts among
+them, is new files and entries, never an edit of the harness.
 """
 
 from __future__ import annotations

@@ -3,13 +3,14 @@ metrics read.
 
 * Device planes are the planes named ``/device:TPU:<n>``; their ``XLA Ops``
   line holds one event per operation that ran, named by its HLO text, and
-  their ``XLA Modules`` line one event per program execution.  The
-  programs the serving path jits from ``functools.partial`` objects all
-  carry the name ``jit__unknown(<fingerprint>)``, so a program is known by
-  the host span that was waiting for it (its ``span``).
+  their ``XLA Modules`` line one event per program execution.  A program
+  is known by the host span that was waiting for it (its ``span``), which
+  also holds for a program that runs under no name of its own.
 * Host spans are the ``bench.*`` annotations the harness opens around the
   calls into each layer; ``bench.window`` spans the traced window, and
-  zero-length ``bench.mark.*`` events carry counts as stats.
+  zero-length ``bench.mark.*`` events carry counts as stats.  The
+  program's own ``serving.*`` spans are read by ``bench/spans.py`` from
+  the same profile.
 
 Busy time is the union of the operation intervals inside the window,
 averaged over the device planes.  An idle gap is named by the innermost
@@ -100,9 +101,19 @@ def _union(intervals):
     return out
 
 
-def reduce_trace(path: str) -> TraceSummary:
+def load_profile(trace):
+    """``trace`` as a ``jax.profiler.ProfileData``: a path to an
+    ``.xplane.pb`` is read, a loaded profile is returned as it is."""
+    if hasattr(trace, "planes"):
+        return trace
     import jax
-    pd = jax.profiler.ProfileData.from_file(str(path))
+    return jax.profiler.ProfileData.from_file(str(trace))
+
+
+def reduce_trace(trace) -> TraceSummary:
+    """Reduce ``trace`` (a path to an ``.xplane.pb``, or its loaded
+    ``jax.profiler.ProfileData``)."""
+    pd = load_profile(trace)
     spans, window = [], None
     devices = []
     for plane in pd.planes:
@@ -127,9 +138,9 @@ def reduce_trace(path: str) -> TraceSummary:
                     spans.append((ev.start_ns, ev.start_ns + ev.duration_ns,
                                   ev.name))
     if window is None:
-        raise ValueError(f"{path}: no {WINDOW_SPAN} span")
+        raise ValueError(f"{trace}: no {WINDOW_SPAN} span")
     if not devices:
-        raise ValueError(f"{path}: no device plane matching "
+        raise ValueError(f"{trace}: no device plane matching "
                          f"{DEVICE_PLANE.pattern}")
     lo, hi = window
     devices.sort()

@@ -9,6 +9,7 @@ reduction sums them.  On the trace recorded on the chip before the spans
 existed, the reduction finds nothing and the accepted metrics read as
 they did."""
 
+import time
 from pathlib import Path
 
 import jax
@@ -157,6 +158,22 @@ def test_old_trace_has_no_spans_and_reads_as_before():
         "prefill_ms_per_ktok": 865.233375,
         "paged_attn_roofline": 2.3847450251771884,
         "device_idle_pct": 13.34410464385497}, rel=1e-9)
+
+
+def test_untraced_run_gives_readers_no_spans(monkeypatch):
+    """``RunData.spans`` is None on an untraced run."""
+    made, cls = [], H.RunData
+
+    def run_data(*a, **k):
+        made.append(cls(*a, **k))
+        return made[-1]
+    monkeypatch.setattr(H, "RunData", run_data)
+    res = H.run_cell(S.resolve_cell(CELL), 2**31 + 29, 3.0, False,
+                     t_start=time.perf_counter(), rehearse=True,
+                     log=lambda s: None)
+    assert res["checks_pass"] is True
+    assert len(made) == 1
+    assert made[0].spans is None and made[0].trace is None
 
 
 def test_innermost_pieces_and_split_by_hand():
